@@ -19,7 +19,7 @@
   ``shard_chunked``) are device-resident by contract; pulling them
   back to host with ``np.asarray``/``np.array``/``jax.device_get`` or
   ``.tolist()`` inside checker-path code silently re-pays the H2D/D2H
-  tunnel the IR exists to avoid. Waivable per line with
+  transfer the IR exists to avoid. Waivable per line with
   ``# lint: ignore[no-host-roundtrip]`` when a host read is the point
   (e.g. a final verdict gather).
 * ``threshold-dtype`` (JTJ005) — ``jnp.dot(...,

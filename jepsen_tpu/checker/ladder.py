@@ -13,10 +13,16 @@ kernel -> native C++ -> CPU — as one policy object:
 * **Resource exhaustion**: an XLA ``RESOURCE_EXHAUSTED`` (device OOM)
   or compile failure gets ONE adaptive retry with halved tile/batch
   sizes (the backend's ``shrink`` hook) before demoting.
-* **Watchdog**: device dispatches run under a timeout — a hung TPU
-  dispatch (dead tunnel, wedged runtime) demotes to the next backend
-  instead of hanging the run. The stuck thread is abandoned (daemon),
-  mirroring ``utils.timeout``.
+* **Watchdog**: device dispatches run under a timeout — a hung
+  dispatch (wedged runtime) demotes to the next backend instead of
+  hanging the run. The stuck thread is abandoned (daemon), mirroring
+  ``utils.timeout``.
+* **Strict device**: a dispatch whose ``ctx["strict_device"]`` is set
+  (the checker's explicit ``accelerator="tpu"``) may demote from one
+  device rung to another, but a device rung's hard failure never
+  settles on a host rung: :class:`DeviceFailed` raises instead, so a
+  broken device can't hide behind a CPU verdict. Declines (out of
+  regime, frontier overflow) still fall through.
 * **Circuit breaker**: ``breaker_threshold`` *consecutive* hard
   failures trip a per-backend breaker; further dispatches skip the
   backend until :meth:`reset`. A flaky accelerator degrades a run to
@@ -55,6 +61,12 @@ class Unavailable(Exception):
     """Raised by a backend to decline a dispatch (capability miss, out of
     regime). A quiet demotion: no failure is counted against the
     backend."""
+
+
+class DeviceFailed(RuntimeError):
+    """A device rung failed under ``strict_device``: settling on a host
+    rung would report a CPU verdict for a run that asked for the
+    device."""
 
 
 class LadderExhausted(Exception):
@@ -270,11 +282,21 @@ class BackendLadder:
                 logger.exception("eligibility probe for %r failed",
                                  backend.name)
                 continue
+            failure = ctx.get("_device_error")
+            if failure is not None and not backend.device \
+                    and ctx.get("strict_device"):
+                raise DeviceFailed(
+                    f"device rung failed and the dispatch is pinned to "
+                    f"the device (attempted: {attempted}): "
+                    f"{failure!r}") from failure
             terminal = backend is last
             # the terminal rung is breaker-exempt: it has no fallback,
             # so skipping it would wedge every subsequent dispatch
             if not terminal and backend.name in self.broken():
                 self._demote(backend.name, "circuit-open")
+                if backend.device:
+                    ctx["_device_error"] = RuntimeError(
+                        f"{backend.name} circuit breaker is open")
                 attempted.append(backend.name)
                 continue
             res = self._attempt(backend, ctx, terminal=terminal)
@@ -358,6 +380,8 @@ class BackendLadder:
                 rung_span("error")
                 if terminal:
                     raise
+                if backend.device:
+                    ctx["_device_error"] = e
                 self._count_failure(backend.name)
                 self._demote(backend.name,
                              "resource-exhausted" if rex
@@ -367,6 +391,9 @@ class BackendLadder:
                 return None
             if res is _TIMED_OUT:
                 rung_span("watchdog-timeout")
+                ctx["_device_error"] = TimeoutError(
+                    f"{backend.name} dispatch exceeded the "
+                    f"{self.watchdog_s:g}s watchdog")
                 if reg.enabled:
                     reg.counter(
                         "checker_watchdog_timeouts_total",

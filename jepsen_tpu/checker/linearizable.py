@@ -10,7 +10,15 @@ the dispatch axes are:
   :accelerator option called for by BASELINE.json's north star. "auto" uses
   the device kernel for histories big enough to amortize compilation and
   falls back to CPU when the device frontier overflows (mirroring the
-  reference's competition mode, checker.clj:199-203).
+  reference's competition mode, checker.clj:199-203). "tpu" pins the
+  device: a device rung that fails raises
+  :class:`~jepsen_tpu.checker.ladder.DeviceFailed` instead of settling on
+  the CPU.
+
+Device verdicts are labelled with the platform that ran them and the
+kernel (``jitlin-tpu-matrix`` on a TPU, ``jitlin-cpu-matrix`` under
+XLA's CPU backend; ``-frontier`` for the event-scan kernel), never with
+the platform that was asked for.
 
 Failure output is truncated (the reference truncates :final-paths/:configs
 to 10 because "Writing these can take *hours*", checker.clj:213-216).
@@ -38,6 +46,15 @@ AUTO_TPU_THRESHOLD = 512
 # Failure reports re-run the exact CPU search to recover the dying
 # frontier; skip that recovery for histories longer than this.
 MAX_REPORT_EVENTS = 200_000
+
+def device_algorithm(suffix: str) -> str:
+    """The result label of a device-kernel verdict: ``jitlin-<platform>``
+    plus the kernel's suffix, naming the JAX platform the kernel ran on
+    (the suffix keeps ``jitlin-cpu-*`` apart from the host search's
+    ``jitlin-cpu``)."""
+    import jax
+    return f"jitlin-{jax.default_backend()}{suffix}"
+
 
 # Backends that have completed at least one dispatch this process: the
 # first call's wall time includes JIT compilation, later calls don't —
@@ -232,6 +249,9 @@ class LinearizableChecker(Checker):
             "spec": spec,
             "history": history,
             "device_regime": device_regime,
+            # accelerator="tpu": a failed device rung raises instead of
+            # settling on a host rung (checker/ladder.py DeviceFailed)
+            "strict_device": accelerator == "tpu",
             "capacity": self.capacity,
             # sharded-rung routing (doc/performance.md): True forces,
             # False disables, None = env default + cost-model gate
@@ -395,7 +415,7 @@ class LinearizableChecker(Checker):
             # capture the phase split on THIS (possibly watchdog) thread;
             # _search_stream re-publishes it on the checker's thread
             ctx["_matrix_phase"] = last_phase_seconds()
-            return matrix_settle(ctx, m, "jitlin-tpu-matrix")
+            return matrix_settle(ctx, m, device_algorithm("-matrix"))
 
         def matrix_shrink(ctx):
             # halve the chunk element budget: _matrix_plan sizes the
@@ -446,7 +466,8 @@ class LinearizableChecker(Checker):
             from jepsen_tpu.ops.jitlin import last_phase_seconds
             m = matrix_rung_check(ctx, mesh=ctx["_sharded_mesh"])
             ctx["_matrix_phase"] = last_phase_seconds()
-            res = matrix_settle(ctx, m, "jitlin-tpu-matrix-sharded")
+            res = matrix_settle(ctx, m,
+                                device_algorithm("-matrix-sharded"))
             if res is not None:
                 return res
             # the screen COMPLETED but didn't settle (inexact, or
@@ -498,7 +519,7 @@ class LinearizableChecker(Checker):
                 failed_op_index=(int(stream.op_index[died])
                                  if died >= 0 else -1),
                 configs_max=peak,
-                algorithm="jitlin-tpu",
+                algorithm=device_algorithm("-frontier"),
             )
 
         def frontier_shrink(ctx):
@@ -624,14 +645,13 @@ class LinearizableChecker(Checker):
                           "events verified per second, last check",
                           labels=("backend",)
                           ).set(n_events / dt, backend=backend)
-            if "tpu" in backend:
+            if backend.startswith("jitlin-tpu"):
                 peak_bytes = telemetry.device_memory_peak_bytes()
                 if peak_bytes is not None:
                     reg.gauge("checker_device_memory_peak_bytes",
                               "device allocator high-water"
                               ).set_max(peak_bytes)
-            if backend.startswith("jitlin-tpu-matrix") and stream is not None \
-                    and dt > 0:
+            if "-matrix" in backend and stream is not None and dt > 0:
                 import numpy as np
                 n_returns = int((np.asarray(stream.kind) == 1).sum())
                 achieved = telemetry.matrix_modeled_flops(
@@ -775,7 +795,7 @@ class LinearizableChecker(Checker):
                 "backend": forensics["backend"],
                 "bisect-steps": forensics["bisect_steps"],
             }
-            if test is not None:
+            if isinstance(test, dict) and test.get("start_time"):
                 arts = explain_mod.write_artifacts(test, history,
                                                    forensics, opts=opts)
                 if arts:
@@ -786,8 +806,8 @@ class LinearizableChecker(Checker):
 
     def _render(self, res, history, test) -> str | None:
         """linear.png into the test's store dir (checker.clj:205-212)."""
-        if test is None:
-            return None
+        if not isinstance(test, dict) or not test.get("start_time"):
+            return None  # no store coordinates: a bare re-check
         try:
             from jepsen_tpu import store
             from jepsen_tpu.checker.linear_report import render_failure

@@ -452,6 +452,8 @@ def single_test_cmd(
             opts = parser.parse_args(argv)
         except SystemExit as e:
             return EXIT_BAD_ARGS if e.code not in (0, None) else 0
+        from jepsen_tpu import compile_cache
+        compile_cache.enable()
 
         try:
             if opts.command == "test":
@@ -1083,6 +1085,8 @@ def test_all_cmd(tests_fn: Callable[[argparse.Namespace], list], name="jepsen-tp
             opts = parser.parse_args(argv)
         except SystemExit:
             return EXIT_BAD_ARGS
+        from jepsen_tpu import compile_cache
+        compile_cache.enable()
         try:
             from jepsen_tpu import core
             from jepsen_tpu.analysis.preflight import PreflightFailed

@@ -183,7 +183,9 @@ class _Child:
 
     def spawn(self) -> "_Child":
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # the roles check on the CPU by design: the parent may hold the
+        # chip, and a child that reached for it would fail or hang
+        env["JAX_PLATFORMS"] = "cpu"
         log = open(self.log_path, "ab")
         self.proc = subprocess.Popen(
             [sys.executable, "-m", "jepsen_tpu.fleet.chaos",

@@ -228,6 +228,8 @@ class FleetDaemon:
 def serve(store_root, **kw) -> None:
     """``jepsen-tpu fleet``: runs the fleet daemon in the foreground
     until interrupted."""
+    from jepsen_tpu import compile_cache
+    compile_cache.enable()
     fd = FleetDaemon(store_root, **kw)
     fd.start()
     try:

@@ -93,6 +93,14 @@ def fuzz_knob(name: str, value, default: float, lo: float | None):
     return v
 
 
+def _cpu_only_worker() -> None:
+    """Process-pool initializer: the worker never reaches for the chip."""
+    import sys
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
 class Hunter:
     """One coverage-guided (or, for the baseline, blind-random) hunt.
 
@@ -165,8 +173,14 @@ class Hunter:
             return histories
         try:
             import concurrent.futures as _fut
+            import multiprocessing
+            # spawned, CPU-pinned workers: they only run fake trials, and
+            # a fork of a parent that holds the chip (the live daemon
+            # checks on self.accelerator) would copy its device state
             with _fut.ProcessPoolExecutor(
-                    max_workers=self.pool_workers) as pool:
+                    max_workers=self.pool_workers,
+                    mp_context=multiprocessing.get_context("spawn"),
+                    initializer=_cpu_only_worker) as pool:
                 for idx, h in pool.map(pool_run_trial, jobs):
                     histories[idx] = h
             return histories

@@ -470,8 +470,5 @@ def subhistories(dh: DeviceHistory) -> tuple[list, dict]:
     several composed checkers lift the same history."""
     def build():
         from jepsen_tpu import independent
-        keys = independent.history_keys(dh.ops)
-        subs = {independent._freeze_key(k):
-                independent.subhistory(k, dh.ops) for k in keys}
-        return keys, subs
+        return independent.split_by_key(dh.ops)
     return dh.view(("subhistories",), build)

@@ -218,30 +218,37 @@ def concurrent_generator(n: int, keys: Iterable, gen_fn: Callable) -> Generator:
     return ConcurrentGenerator(n=n, keys=KeyStream(keys), gen_fn=gen_fn)
 
 
-def history_keys(history: list[dict]) -> list:
-    """All keys in a lifted history (independent.clj:238-248)."""
-    seen = {}
+def _freeze_key(k):
+    return tuple(k) if isinstance(k, list) else k
+
+
+def split_by_key(history: list[dict]) -> tuple[list, dict]:
+    """``(keys, {frozen_key: sub_history})`` in ONE pass over the
+    history, inner values unwrapped (independent.clj:238-262). A pass
+    per key would be O(keys × history): hours of host time at 1024
+    keys × 2M events."""
+    keys: dict = {}
+    subs: dict = {}
     for op in history:
         v = op.get("value")
         if is_tuple_value(v):
-            seen.setdefault(_freeze_key(v[0]), v[0])
-    return list(seen.values())
+            fk = _freeze_key(v[0])
+            if fk not in subs:
+                keys[fk] = v[0]
+                subs[fk] = []
+            subs[fk].append({**op, "value": v[1]})
+    return list(keys.values()), subs
 
 
-def _freeze_key(k):
-    return tuple(k) if isinstance(k, list) else k
+def history_keys(history: list[dict]) -> list:
+    """All keys in a lifted history (independent.clj:238-248)."""
+    return split_by_key(history)[0]
 
 
 def subhistory(k, history: list[dict]) -> list[dict]:
     """The sub-history for key k, with inner values unwrapped
     (independent.clj:250-262)."""
-    fk = _freeze_key(k)
-    out = []
-    for op in history:
-        v = op.get("value")
-        if is_tuple_value(v) and _freeze_key(v[0]) == fk:
-            out.append({**op, "value": v[1]})
-    return out
+    return split_by_key(history)[1].get(_freeze_key(k), [])
 
 
 class IndependentChecker(Checker):
@@ -314,8 +321,7 @@ class IndependentChecker(Checker):
             from jepsen_tpu.history_ir import views
             keys, subs = views.subhistories(ir)
         else:
-            keys = history_keys(history)
-            subs = {_freeze_key(k): subhistory(k, history) for k in keys}
+            keys, subs = split_by_key(history)
         if not keys:
             return {"valid?": True, "results": {}, "count": 0}
 
@@ -408,9 +414,10 @@ class IndependentChecker(Checker):
                 accelerator="auto" if accelerator == "auto" else "device",
                 mesh=mesh, mesh_devices=mesh_devices)
             route = par.last_route()
+            from jepsen_tpu.checker.linearizable import device_algorithm
             backend = {"cpu": "jitlin-cpu(routed)",
-                       "mesh": "jitlin-tpu-sharded"}.get(route,
-                                                         "jitlin-tpu")
+                       "mesh": device_algorithm("-batch-sharded")}.get(
+                route, device_algorithm("-batch"))
             from jepsen_tpu.checker import explain as explain_mod
             explain_on = explain_mod.enabled(test, opts)
             results = {}
@@ -476,6 +483,8 @@ class IndependentChecker(Checker):
                 }
             return merged
         except Exception:  # noqa: BLE001
+            if accelerator == "tpu":
+                raise    # pinned to the device: no silent per-key lane
             logger.exception("batched independent check failed; "
                              "falling back to per-key")
             return None

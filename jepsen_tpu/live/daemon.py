@@ -961,6 +961,8 @@ class LiveDaemon:
 def serve(store_root: str | None, run_dirs=(), **kw) -> None:
     """``jepsen-tpu live``: runs the daemon in the foreground until
     interrupted."""
+    from jepsen_tpu import compile_cache
+    compile_cache.enable()
     daemon = LiveDaemon(store_root=store_root, run_dirs=run_dirs, **kw)
     daemon.start()
     logger.info("live checker daemon polling every %.3gs (ctrl-C stops)",

@@ -35,8 +35,24 @@ def _build_dir() -> Path:
     return Path(d) if d else _HERE
 
 
+def host_cpu_tag() -> bytes:
+    """The host CPU's model and feature flags: ``-march=native`` code
+    runs only where they match, so they key every built artifact (a
+    tree copied to another machine rebuilds instead of dying of
+    SIGILL)."""
+    try:
+        with open("/proc/cpuinfo", "rb") as fh:
+            lines = fh.read().splitlines()
+    except OSError:
+        return b""
+    keep = [ln for ln in lines
+            if ln.startswith((b"model name", b"flags"))]
+    return b"\n".join(keep[:2])
+
+
 def _so_path(san: bool = False) -> Path:
-    src_hash = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    src_hash = hashlib.sha256(_SRC.read_bytes()
+                              + host_cpu_tag()).hexdigest()[:16]
     stem = "_libwgl_san" if san else "_libwgl"
     return _build_dir() / f"{stem}-{src_hash}.so"
 

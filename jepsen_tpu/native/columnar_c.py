@@ -52,7 +52,9 @@ def _build_dir() -> Path:
 
 
 def _so_path(san: bool = False) -> Path:
-    src_hash = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    from jepsen_tpu.native import host_cpu_tag
+    src_hash = hashlib.sha256(_SRC.read_bytes()
+                              + host_cpu_tag()).hexdigest()[:16]
     stem = "_columnar_c_san" if san else "_columnar_c"
     return _build_dir() / f"{stem}-{src_hash}.so"
 

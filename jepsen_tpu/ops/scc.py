@@ -152,7 +152,7 @@ def run_sharded_trim(mesh, n_nodes: int, sj, dj, wj, max_iters: int = 512):
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]

@@ -156,7 +156,7 @@ class TestPreflightDiagnostics:
         diags = _pf(t)
         assert "KNB007" in _codes(diags)
         assert "KNB007" not in _codes(_pf(_atom_test(
-            matrix_variant="packed")))
+            matrix_variant="int8")))
         assert "KNB007" not in _codes(_pf(_atom_test(
             matrix_variant="auto")))
 

@@ -320,7 +320,7 @@ def test_linearizable_forced_oom_demotes_to_cpu(metrics_registry,
                            "allocating frontier")
 
     monkeypatch.setattr(JitLinKernel, "check", oom)
-    checker = LinearizableChecker(accelerator="tpu", watchdog_s=0)
+    checker = LinearizableChecker(accelerator="auto", watchdog_s=0)
     out = checker.check({}, _register_history(300), {})
     assert out["valid?"] is True
     assert out["algorithm"] == "jitlin-cpu(fallback)"
@@ -330,6 +330,54 @@ def test_linearizable_forced_oom_demotes_to_cpu(metrics_registry,
                           reason="resource-exhausted") == 1
     assert _counter_value(reg, "checker_backend_shrink_retries_total",
                           backend="jitlin-device") == 1
+
+
+@pytest.mark.parametrize("failure", ["oom", "error"])
+def test_linearizable_tpu_pinned_device_failure_is_loud(failure,
+                                                        monkeypatch):
+    """accelerator="tpu" pins the device: a device rung that fails
+    (after its shrink retry) raises DeviceFailed instead of settling a
+    CPU verdict, while "auto" keeps demoting to the CPU rung."""
+    from jepsen_tpu.checker.ladder import DeviceFailed
+    from jepsen_tpu.checker.linearizable import LinearizableChecker
+    from jepsen_tpu.ops.jitlin import JitLinKernel
+
+    msg = ("RESOURCE_EXHAUSTED: out of memory" if failure == "oom"
+           else "injected device failure")
+
+    def boom(self, stream, capacity=256):
+        raise RuntimeError(msg)
+
+    monkeypatch.setattr(JitLinKernel, "check", boom)
+    with pytest.raises(DeviceFailed):
+        LinearizableChecker(accelerator="tpu", watchdog_s=0).check(
+            {}, _register_history(300), {})
+    out = LinearizableChecker(accelerator="auto", watchdog_s=0).check(
+        {}, _register_history(300), {})
+    assert out["algorithm"] == "jitlin-cpu(fallback)"
+
+
+def test_ladder_strict_device_demotes_between_device_rungs():
+    """Under strict_device a failed device rung may still hand over to
+    the next DEVICE rung; only the step onto a host rung raises."""
+    from jepsen_tpu.checker.ladder import Backend, BackendLadder, DeviceFailed
+
+    def bad(ctx):
+        raise RuntimeError("sharded collective failed")
+
+    ladder = BackendLadder([
+        Backend("mesh", bad, device=True),
+        Backend("single", lambda ctx: "dev", device=True),
+        Backend("cpu", lambda ctx: "host"),
+    ], watchdog_s=0)
+    assert ladder.run({"strict_device": True}) == ("dev", "single")
+    ladder = BackendLadder([
+        Backend("mesh", bad, device=True),
+        Backend("cpu", lambda ctx: "host"),
+    ], watchdog_s=0)
+    assert ladder.run({}) == ("host", "cpu")
+    with pytest.raises(DeviceFailed):
+        ladder.run({"strict_device": True})
 
 
 def test_linearizable_ladder_bit_identical_host_path():

@@ -124,7 +124,7 @@ def test_matrix_localize_sharded_mesh_checker():
     res = LinearizableChecker(accelerator="tpu").check(
         {}, h, {"checker_sharded": True})
     assert res["valid?"] is False
-    assert res["algorithm"] == "jitlin-tpu-matrix-sharded", res["algorithm"]
+    assert res["algorithm"] == "jitlin-cpu-matrix-sharded", res["algorithm"]
     assert res["explain"]["first-anomaly-op"] == cpu.failed_op_index
 
 
@@ -144,13 +144,13 @@ def test_ladder_settles_invalid_at_matrix_rung():
         res = LinearizableChecker(accelerator="tpu").check(
             {}, h, {"checker_sharded": False})
         assert res["valid?"] is False
-        assert res["algorithm"] == "jitlin-tpu-matrix", res["algorithm"]
+        assert res["algorithm"] == "jitlin-cpu-matrix", res["algorithm"]
         assert res["failed-op"] == h[cpu.failed_op_index]
         assert res["explain"]["first-anomaly-op"] == cpu.failed_op_index
         snap = {(r["name"], tuple(sorted((r.get("labels") or {}).items())))
                 for r in reg.snapshot()}
         assert ("checker_backend_total",
-                (("backend", "jitlin-tpu-matrix"),)) in snap
+                (("backend", "jitlin-cpu-matrix"),)) in snap
         names = {r["name"] for r in reg.snapshot()}
         assert {"explain_bisect_steps", "explain_latency_seconds",
                 "witness_ops"} <= names
@@ -169,7 +169,7 @@ def test_explain_off_restores_demotion_path():
     res = LinearizableChecker(accelerator="tpu").check(
         {"explain": False}, h, {"checker_sharded": False})
     assert res["valid?"] is False
-    assert res["algorithm"] != "jitlin-tpu-matrix"
+    assert res["algorithm"] != "jitlin-cpu-matrix"
     assert "explain" not in res
     assert res["failed-op"] == h[cpu.failed_op_index]
 

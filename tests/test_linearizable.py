@@ -409,7 +409,7 @@ def test_linearizable_checker_selects_matrix_path():
     h = _register_history(3000, n_procs=4, seed=11, n_values=5)
     res = LinearizableChecker(accelerator="tpu").check({}, h, {})
     assert res["valid?"] is True
-    assert res["algorithm"] == "jitlin-tpu-matrix", res["algorithm"]
+    assert res["algorithm"] == "jitlin-cpu-matrix", res["algorithm"]
 
 
 # ---------------------------------------------------------------------------

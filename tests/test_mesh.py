@@ -117,7 +117,7 @@ def test_segmented_variant_chain_matches_mesh_twin(monkeypatch):
     from jepsen_tpu.ops import jitlin
 
     mesh = _mesh()
-    for variant in ("packed", "int8"):
+    for variant in ("int8", "f32"):
         intern = Intern()
         segs = [_stream(120, seed=10 + s, intern=intern) for s in range(2)]
         outs = {}
@@ -233,7 +233,7 @@ def test_ladder_sharded_rung_wins(metrics_registry):
     out = chk.check({}, _matrix_regime_history(),
                     {"checker_sharded": True})
     assert out["valid?"] is True
-    assert out["algorithm"] == "jitlin-tpu-matrix-sharded"
+    assert out["algorithm"] == "jitlin-cpu-matrix-sharded"
 
 
 def test_ladder_sharded_demotes_to_single_device(metrics_registry,
@@ -259,7 +259,7 @@ def test_ladder_sharded_demotes_to_single_device(metrics_registry,
     out = chk.check({}, _matrix_regime_history(),
                     {"checker_sharded": True})
     assert out["valid?"] is True
-    assert out["algorithm"] == "jitlin-tpu-matrix"  # single-device won
+    assert out["algorithm"] == "jitlin-cpu-matrix"  # single-device won
     reg = metrics_registry
     demoted = reg.counter("checker_backend_demotions_total",
                           labels=("backend", "reason")).value(
@@ -275,7 +275,7 @@ def test_ladder_sharded_disabled_by_knob(metrics_registry):
     out = chk.check({}, _matrix_regime_history(),
                     {"checker_sharded": False})
     assert out["valid?"] is True
-    assert out["algorithm"] == "jitlin-tpu-matrix"
+    assert out["algorithm"] == "jitlin-cpu-matrix"
 
 
 # ---------------------------------------------------------------------------

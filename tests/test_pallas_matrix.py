@@ -1,6 +1,6 @@
 """Pallas transfer-matrix kernels (ops/pallas_matrix.py).
 
-CPU tier: every kernel variant (f32 / int8-MXU / bit-packed uint32) and
+CPU tier: every kernel variant (f32 / int8-MXU) at two (S, V) shapes and
 every L-build mode (in-kernel dots / VMEM pretile / HBM-streamed
 pretile), plus the fused streaming combine, run in pallas interpret
 mode and are differentially pinned against (a) an independent numpy
@@ -14,8 +14,11 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-VARIANTS = ("f32", "int8", "packed")
+VARIANTS = ("f32", "int8")
 MODES = ("none", "vmem", "hbm")
+# (variant, S, V): every variant at MV=64, plus int8 at a second MV=64
+# factorization (fewer slots, wider value domain)
+CASES = (("f32", 3, 8), ("int8", 3, 8), ("int8", 2, 16))
 
 
 def _oracle(S, V, pend, ids, mtT, slots, valid):
@@ -64,14 +67,14 @@ def test_static_tables_express_kron_and_kill():
         assert np.array_equal((Kexp[s] @ B > 0) * 1.0, (ref > 0) * 1.0), s
 
 
-@pytest.mark.parametrize("variant", VARIANTS)
-def test_kernel_matches_numpy_oracle_interpret(variant):
+@pytest.mark.parametrize("variant,S,V", CASES)
+def test_kernel_matches_numpy_oracle_interpret(variant, S, V):
     """Every representation variant is bit-identical to the numpy
     oracle on a random run — the identity the auto-probe re-verifies
     per (S, V, variant) before a production dispatch."""
     from jepsen_tpu.ops.pallas_matrix import _build
 
-    S, V, T, U, G = 3, 8, 5, 16, 4        # MV=64: packed word-aligned
+    T, U, G = 5, 16, 4
     pend, ids, mtT, slots, valid = _inputs(S, V, T, U, G)
     ref = _oracle(S, V, pend, ids, mtT, slots, valid)
     fn = _build(S, V, T, U, interpret=True, variant=variant)
@@ -79,9 +82,9 @@ def test_kernel_matches_numpy_oracle_interpret(variant):
     assert np.array_equal(ref, got), variant
 
 
-@pytest.mark.parametrize("variant", VARIANTS)
+@pytest.mark.parametrize("variant,S,V", CASES)
 @pytest.mark.parametrize("mode", MODES)
-def test_lbuild_modes_match_oracle_interpret(variant, mode):
+def test_lbuild_modes_match_oracle_interpret(variant, S, V, mode):
     """All three L-build data paths — in-kernel tiling dots, the VMEM
     pre-tiled table, and the HBM-streamed double-buffered table — are
     bit-identical to the oracle for every variant (the hbm mode is what
@@ -89,7 +92,7 @@ def test_lbuild_modes_match_oracle_interpret(variant, mode):
     L-build)."""
     from jepsen_tpu.ops.pallas_matrix import _build
 
-    S, V, T, U, G = 3, 8, 5, 16, 4
+    T, U, G = 5, 16, 4
     pend, ids, mtT, slots, valid = _inputs(S, V, T, U, G, seed=3)
     ref = _oracle(S, V, pend, ids, mtT, slots, valid)
     fn = _build(S, V, T, U, interpret=True, pretile=mode, variant=variant)
@@ -103,14 +106,14 @@ def test_pretile_mode_selection(monkeypatch):
     extend the VMEM budget 4x over f32."""
     import jepsen_tpu.ops.pallas_matrix as pm
 
-    S, V = 3, 8            # MV=64 -> one f32 tile = 16 KiB
+    S, V = 3, 16           # MV=128 -> one f32 tile = 64 KiB
     assert pm._pretile_mode(S, V, 16, "f32") == "vmem"
-    monkeypatch.setattr(pm, "PALLAS_PRETILE_BYTES", 16 * 64 * 64)
+    monkeypatch.setattr(pm, "PALLAS_PRETILE_BYTES", 16 * 128 * 128)
     # f32 tables now blow the VMEM budget at U=16; the int8 table is
     # 4x smaller and still fits
     assert pm._pretile_mode(S, V, 16, "f32") == "hbm"
     assert pm._pretile_mode(S, V, 16, "int8") == "vmem"
-    monkeypatch.setattr(pm, "PALLAS_PRETILE_HBM_BYTES", 16 * 64 * 64)
+    monkeypatch.setattr(pm, "PALLAS_PRETILE_HBM_BYTES", 16 * 128 * 128)
     assert pm._pretile_mode(S, V, 64, "f32") == "none"
 
 
@@ -192,8 +195,10 @@ def test_production_dispatch_variant_parity(monkeypatch):
 
 
 @pytest.mark.explain
-@pytest.mark.parametrize("variant", ["packed", "int8", "f32"])
-def test_variant_verdict_localizes_to_frontier(variant, monkeypatch):
+@pytest.mark.parametrize("variant,n_values", [("int8", 4), ("int8", 5),
+                                              ("f32", 4)])
+def test_variant_verdict_localizes_to_frontier(variant, n_values,
+                                                monkeypatch):
     """ISSUE 12 (explain tier): an INVALID verdict from each pallas
     kernel variant (interpret mode) localizes to the same
     first-return/event as the exact CPU frontier — the representation
@@ -207,7 +212,7 @@ def test_variant_verdict_localizes_to_frontier(variant, monkeypatch):
     from jepsen_tpu.checker.linear_encode import encode_register_ops
     from jepsen_tpu.ops.jitlin import matrix_check, matrix_localize
 
-    h = _register_history(160, n_procs=3, seed=6, n_values=4)
+    h = _register_history(160, n_procs=3, seed=6, n_values=n_values)
     import random
     reads = [op for op in h
              if op.get("f") == "read" and op.get("type") == "ok"]
@@ -246,7 +251,7 @@ def test_checker_knobs_route_variant(monkeypatch):
                     _register_history(240, n_procs=3, seed=3, n_values=5),
                     {})
     assert out["valid?"] is True
-    assert out["algorithm"] == "jitlin-tpu-matrix"
+    assert out["algorithm"] == "jitlin-cpu-matrix"
     split = jitlin.last_phase_seconds()
     assert split.get("variant") == "int8", split
     assert split.get("combine") == "fused", split
@@ -266,19 +271,19 @@ def test_variant_runtime_failure_demotes(monkeypatch):
     real_build = pm._build.__wrapped__
 
     def bomb(S, V, T, U, interpret=False, pretile="none", variant="f32"):
-        if variant == "packed":
-            raise RuntimeError("synthetic packed lowering failure")
+        if variant == "int8":
+            raise RuntimeError("synthetic int8 lowering failure")
         return real_build(S, V, T, U, interpret, pretile, variant)
 
     bomb.__wrapped__ = bomb
     import functools
     monkeypatch.setattr(pm, "_build", functools.lru_cache(maxsize=32)(bomb))
     h = _register_history(60, n_procs=3, seed=5, n_values=4)
-    m = matrix_check(encode_register_ops(h), force=True, variant="packed")
+    m = matrix_check(encode_register_ops(h), force=True, variant="int8")
     assert m is not None and m[0] is True
     info = last_dispatch_info()
-    assert info["variant"] == "int8", info     # demoted one rung down
-    assert (3, 8, "packed") in pm._DISABLED
+    assert info["variant"] == "f32", info      # demoted one rung down
+    assert (3, 8, "int8") in pm._DISABLED
 
 
 def test_gates(monkeypatch):
@@ -287,11 +292,13 @@ def test_gates(monkeypatch):
     # VMEM caps: decline huge operator dimensions
     assert pm.chunk_product(9, 8, 4, 16) is None        # S over cap
     assert pm.chunk_product(8, 16, 4, 16) is None       # MV = 4096 over cap
-    # packed caps: word alignment and the AND-intermediate MV bound
-    assert pm.variant_ok("packed", 1, 8) is False       # MV=16 not /32
-    assert pm.variant_ok("packed", 5, 16) is False      # MV=512 > cap
-    assert pm.variant_ok("packed", 3, 8) is True        # MV=64
-    assert pm.variant_ok("int8", 5, 16) is True
+    assert pm.variant_ok("packed", 3, 8) is False       # removed variant
+    assert pm.variant_ok("int8", 1, 8) is True          # MV=16
+    assert pm.variant_ok("int8", 5, 16) is True         # MV=512 at the cap
+    # hbm DMA tiles need a 128-lane-aligned MV: below it the table
+    # keeps the in-kernel dots however big it grows
+    assert pm._pretile_mode(3, 8, 2048, "f32") == "none"    # MV=64
+    assert pm._pretile_mode(4, 16, 512, "f32") == "hbm"     # MV=256
     assert pm.variant_ok("bf16", 3, 8) is False         # unknown name
     # env kill-switch (monkeypatch restores any externally-set value)
     monkeypatch.setenv("JEPSEN_TPU_NO_PALLAS", "1")
@@ -310,8 +317,10 @@ def test_env_and_knob_coercion(monkeypatch):
     follows)."""
     import jepsen_tpu.ops.pallas_matrix as pm
 
-    monkeypatch.setenv("JEPSEN_TPU_MATRIX_VARIANT", "Packed")
-    assert pm.matrix_variant() == "packed"
+    monkeypatch.setenv("JEPSEN_TPU_MATRIX_VARIANT", "Int8")
+    assert pm.matrix_variant() == "int8"
+    monkeypatch.setenv("JEPSEN_TPU_MATRIX_VARIANT", "packed")
+    assert pm.matrix_variant() == "auto"
     monkeypatch.setenv("JEPSEN_TPU_MATRIX_VARIANT", "bf16")
     assert pm.matrix_variant() == "auto"
     monkeypatch.setenv("JEPSEN_TPU_PALLAS_PROBE", "FORCE")
@@ -365,8 +374,8 @@ def test_probe_sidecar_cache(monkeypatch, tmp_path):
     # skip: gates only, no probe, nothing persisted for this key
     monkeypatch.setenv("JEPSEN_TPU_PALLAS_PROBE", "skip")
     monkeypatch.setattr(pm, "_PROBED", {})
-    assert pm.enabled(3, 8, "packed") is True
-    assert "packed" not in calls
+    assert pm.enabled(3, 8, "f32") is True
+    assert "f32" not in calls
 
     # a persisted MISS also sticks across processes
     monkeypatch.setenv("JEPSEN_TPU_PALLAS_PROBE", "auto")
@@ -424,14 +433,14 @@ def test_best_variant_order_and_demotion(monkeypatch):
     monkeypatch.setattr(pm, "_PROBED", {})
     monkeypatch.setattr(pm, "_DISABLED", set())
     monkeypatch.delenv("JEPSEN_TPU_MATRIX_VARIANT", raising=False)
-    verdicts = {"packed": False, "int8": True, "f32": True}
+    verdicts = {"int8": False, "f32": True}
     monkeypatch.setattr(
         pm, "enabled",
         lambda S, V, variant="f32": verdicts.get(variant, False))
+    assert pm.best_variant(3, 8) == "f32"
+    assert pm.best_variant(3, 8, force="int8") == "f32"   # demoted
+    assert pm.best_variant(3, 8, force="packed") == "f32"  # unknown name
+    verdicts.update({"int8": True})
     assert pm.best_variant(3, 8) == "int8"
-    assert pm.best_variant(3, 8, force="packed") == "int8"  # demoted
-    assert pm.best_variant(3, 8, force="f32") == "f32"
-    verdicts.update({"packed": True})
-    assert pm.best_variant(3, 8) == "packed"
     monkeypatch.setenv("JEPSEN_TPU_MATRIX_VARIANT", "f32")
     assert pm.best_variant(3, 8) == "f32"

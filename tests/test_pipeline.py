@@ -246,7 +246,7 @@ def test_checker_exports_phase_gauges(monkeypatch):
         chk = LinearizableChecker(accelerator="tpu")
         out = chk.check({}, _register_history(600, n_procs=3, seed=3,
                                               n_values=5), {})
-    assert out["algorithm"] == "jitlin-tpu-matrix"
+    assert out["algorithm"] == "jitlin-cpu-matrix"
     phases = {r["labels"]["phase"] for r in reg.snapshot()
               if r["name"] == "checker_matrix_phase_seconds"}
     assert {"prepass", "grids", "dispatch", "fetch"} <= phases

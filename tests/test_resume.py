@@ -379,7 +379,7 @@ def test_watchdog_demotion_resumes_from_carry(monkeypatch,
 
     monkeypatch.setattr(jitlin.JitLinKernel, "check", no_frontier_kernel)
 
-    chk = LinearizableChecker(accelerator="tpu", watchdog_s=3.0)
+    chk = LinearizableChecker(accelerator="auto", watchdog_s=3.0)
     out = chk.check({}, history, {"checker_sharded": False})
     assert out["valid?"] == full.valid
     assert out["algorithm"] == "jitlin-cpu(fallback)"
@@ -504,7 +504,7 @@ def test_device_failure_shrinks_mesh_bit_identical(monkeypatch,
     monkeypatch.setattr(jitlin, "matrix_check", flaky_on_8)
     chk = LinearizableChecker(accelerator="tpu")
     out = chk.check({}, history, {"checker_sharded": True})
-    assert out["algorithm"] == "jitlin-tpu-matrix-sharded", \
+    assert out["algorithm"] == "jitlin-cpu-matrix-sharded", \
         "the shrunken mesh must settle the check — not single-device"
     shrunk = metrics_registry.counter(
         "mesh_shrink_total", labels=("from", "to")).value(
@@ -615,7 +615,7 @@ def test_sigkill_mid_check_resumes_bit_identical(tmp_path, monkeypatch,
         np.asarray(_stream(4096).kind), 2048))
     out = LinearizableChecker(accelerator="tpu").check(test, history, {})
     assert out["valid?"] is True
-    assert out["algorithm"] == "jitlin-tpu-matrix"
+    assert out["algorithm"] == "jitlin-cpu-matrix"
     assert _resume_count(metrics_registry, "ckpt") == 1
     assert 1 <= len(calls) < n_cuts, \
         f"resume re-ran {len(calls)}/{n_cuts} segments"
