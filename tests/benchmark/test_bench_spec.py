@@ -1,0 +1,125 @@
+"""BENCHMARK.json against the benchmark's contract, and every name in it
+resolving to its file."""
+from __future__ import annotations
+
+import importlib
+import json
+import re
+
+import pytest
+
+from benchmark import harness
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+WIDTH_WORDS = ("hidden", "intermediate", "latent", "state", "projection",
+               "head", "expansion", "experts_per_token")
+
+SPEC = json.loads(harness.SPEC.read_text())
+
+
+def line_ok(s: str) -> bool:
+    return 1 <= len(s) <= 200 and "\n" not in s and "\t" not in s
+
+
+def test_top_level_keys():
+    assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert 1 <= SPEC["run_seconds"] <= 51
+    assert isinstance(SPEC["run_seconds"], int)
+    assert len(harness.SPEC.read_bytes()) <= 64 * 1024
+
+
+def test_command_and_paths():
+    cmd = SPEC["command"]
+    assert 1 <= len(cmd) <= 32 and all(line_ok(w) for w in cmd)
+    assert cmd[1] == "benchmark/run.py"
+    for p in SPEC["paths"]:
+        assert re.fullmatch(r"[A-Za-z0-9_./-]{1,200}", p)
+        assert not p.startswith("/") and ".." not in p.split("/")
+    assert any(cmd[1].startswith(p + "/") for p in SPEC["paths"])
+
+
+@pytest.mark.parametrize("key", ["configs", "workloads", "end_to_end",
+                                 "per_layer"])
+def test_names_unique_and_well_formed(key):
+    names = [e["name"] for e in SPEC[key]]
+    assert len(names) == len(set(names))
+    assert all(NAME.match(n) for n in names), names
+
+
+def test_metric_entries():
+    e2e = {m["name"] for m in SPEC["end_to_end"]}
+    assert "setup_s" in e2e
+    cells = {w["name"] for w in SPEC["workloads"]}
+    for m in SPEC["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source",
+                          "workloads"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    for m in SPEC["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert m["source"] in SOURCES and line_ok(m["layer"])
+        assert m["moves"] in e2e
+        assert set(m.get("workloads", cells)) <= cells
+    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
+        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+        if m["name"].endswith("_roofline") or m["name"] == "device_idle_share":
+            assert m["unit"] == "%"
+
+
+def test_every_cell_reports_enough():
+    for w in SPEC["workloads"]:
+        e2e = [m for m in SPEC["end_to_end"]
+               if w["name"] in m.get("workloads", [w["name"]])]
+        layer = [m for m in SPEC["per_layer"]
+                 if w["name"] in m.get("workloads", [w["name"]])]
+        assert "setup_s" in {m["name"] for m in e2e} and len(e2e) >= 2
+        assert layer, w["name"]
+
+
+def test_cells():
+    pairs = set()
+    configs = {c["name"] for c in SPEC["configs"]}
+    four = 0
+    for w in SPEC["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert w["config"] in configs and NAME.match(w["traffic"])
+        assert w["chips"] in (1, 4) and line_ok(w["why"])
+        assert (w["config"], w["traffic"]) not in pairs
+        pairs.add((w["config"], w["traffic"]))
+        four += w["chips"] == 4
+    assert four <= max(1, len(SPEC["workloads"]) // 2)
+    assert configs == {w["config"] for w in SPEC["workloads"]}
+
+
+def test_configs_resolve_and_reduce_no_width():
+    files = set()
+    for c in SPEC["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert line_ok(c["source"]) and line_ok(c["why"])
+        assert c["file"] == f"benchmark/configs/{c['name']}.json"
+        assert c["file"] not in files
+        files.add(c["file"])
+        body = json.loads((harness.REPO / c["file"]).read_text())
+        assert body["source"] == c["source"]
+        assert body["reduced"] == c["reduced"]
+        assert len(c["reduced"]) <= 16
+        for key in c["reduced"]:
+            assert NAME.match(key) and key in body
+            assert not key.endswith(("_dim", "_rank"))
+            assert not any(w in key for w in WIDTH_WORDS), key
+        importlib.import_module(f"benchmark.checkers.{body['checker']}")
+
+
+def test_every_name_finds_its_files():
+    b = harness.load()
+    for w in SPEC["workloads"]:
+        mix = b.mix(w)
+        assert mix.ops_per_key > 0 and mix.pool >= 1
+        b.checker(w)
+        for trace in (False, True):
+            for m in b.metrics(w, trace):
+                assert callable(b.reader(m).read)
