@@ -233,9 +233,6 @@ def device_roofline() -> dict:
         chain(a).block_until_ready()
         _, ts = _trials(lambda: chain(a).block_until_ready(), 3)
         measured[key] = reps * 2.0 * n ** 3 / min(ts)
-    # publish the measured peak so runtime roofline gauges (checker
-    # telemetry) share bench's denominator
-    telemetry.set_device_peak_flops(measured["f32_matmul_flops"])
     big = jnp.ones((64 * 1024 * 1024,), jnp.float32)   # 256 MB
     bw_reps = 64
 

@@ -39,6 +39,7 @@ history.
 """
 from __future__ import annotations
 
+import contextvars
 import logging
 import os
 import threading
@@ -241,20 +242,40 @@ class BackendLadder:
 
     # -- dispatch -----------------------------------------------------------
 
+    @staticmethod
+    def _run_rung(backend: Backend, ctx: dict) -> Any:
+        """``backend.fn(ctx)`` inside its ``ladder.rung`` phase, on the
+        thread that runs it."""
+        from jepsen_tpu import trace as trace_mod
+        with trace_mod.phase("ladder.rung", backend=backend.name) as span:
+            try:
+                res = backend.fn(ctx)
+            except Unavailable:
+                span.set(outcome="unavailable")
+                raise
+            except BaseException:
+                span.set(outcome="error")
+                raise
+            span.set(outcome="declined" if res is None else "settled")
+            return res
+
     def _call(self, backend: Backend, ctx: dict) -> Any:
         """One invocation, under the watchdog for device rungs."""
         if not backend.device or not self.watchdog_s:
-            return backend.fn(ctx)
+            return self._run_rung(backend, ctx)
         result: list = []
         error: list = []
 
         def run():
             try:
-                result.append(backend.fn(ctx))
+                result.append(self._run_rung(backend, ctx))
             except BaseException as e:  # noqa: BLE001
                 error.append(e)
 
-        t = threading.Thread(target=run, daemon=True,
+        # the caller's context rides along, so the rung's phases carry
+        # the id of the check that dispatched it
+        t = threading.Thread(target=contextvars.copy_context().run,
+                             args=(run,), daemon=True,
                              name=f"jepsen-checker-{backend.name}")
         t.start()
         t.join(self.watchdog_s)

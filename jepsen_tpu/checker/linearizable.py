@@ -29,7 +29,7 @@ import logging
 import time
 from typing import Any
 
-from jepsen_tpu import telemetry
+from jepsen_tpu import telemetry, trace
 from jepsen_tpu.checker import Checker
 from jepsen_tpu.checker.linear_cpu import (
     LinearResult, cas_register_step_py, check_stream, wgl,
@@ -141,6 +141,10 @@ class LinearizableChecker(Checker):
         return self._kernel
 
     def check(self, test, history, opts):
+        with trace.phase(trace.CHECK_SPAN, ops=len(history), keys=1):
+            return self._check(test, history, opts)
+
+    def _check(self, test, history, opts):
         algorithm = opts.get("algorithm", self.algorithm)
         accelerator = opts.get("accelerator", self.accelerator)
         # multi-device sharding knobs (doc/performance.md "Multi-device
@@ -174,7 +178,12 @@ class LinearizableChecker(Checker):
         # IR when one is attachable (history_ir.of memoizes on the test
         # map, so composed checkers share a single encode)
         from jepsen_tpu import history_ir
-        enc = self._encoding(history, ir=history_ir.of(test, history))
+        with trace.phase("encode.ir", events=len(history)):
+            ir = history_ir.of(test, history)
+        with trace.phase("encode.stream", keys=1) as span:
+            enc = self._encoding(history, ir=ir)
+            if enc is not None:
+                span.set(events=len(enc[0]))
         if enc is None:
             res = wgl(history, self.model)
             self._record_metrics(res, time.perf_counter() - t0,
@@ -615,8 +624,7 @@ class LinearizableChecker(Checker):
         """Runtime telemetry for one check dispatch: which backend won,
         first-call (JIT compile included) vs steady-state latency,
         events/sec, device-memory high-water, and — on the matrix path —
-        the achieved-FLOPs/roofline gauges using bench.py's modeled-peak
-        accounting (telemetry.matrix_modeled_flops)."""
+        the matrix dispatch's phase split."""
         reg = telemetry.get_registry()
         if not reg.enabled:
             return
@@ -652,24 +660,10 @@ class LinearizableChecker(Checker):
                               "device allocator high-water"
                               ).set_max(peak_bytes)
             if "-matrix" in backend and stream is not None and dt > 0:
-                import numpy as np
-                n_returns = int((np.asarray(stream.kind) == 1).sum())
-                achieved = telemetry.matrix_modeled_flops(
-                    n_returns, stream.n_slots, len(stream.intern)) / dt
-                reg.gauge("checker_achieved_matmul_flops",
-                          "modeled matrix-kernel FLOP/s, last check"
-                          ).set(achieved)
-                peak = telemetry.device_peak_flops()
-                if peak:
-                    reg.gauge(
-                        "checker_roofline_frac",
-                        "achieved / measured f32 matmul peak "
-                        "(see doc/observability.md)").set(achieved / peak)
                 # per-phase attribution (doc/performance.md): where the
-                # dispatch wall went — host encode (prepass/grids) vs
-                # the async call vs device compute + readback. A small
-                # roofline_frac with small host phases is fixed
-                # round-trip overhead, not kernel inefficiency.
+                # matrix dispatch wall went — host encode
+                # (prepass/grids) vs the async call vs device compute +
+                # readback
                 from jepsen_tpu.ops.jitlin import last_phase_seconds
                 phase_g = reg.gauge(
                     "checker_matrix_phase_seconds",
@@ -714,22 +708,24 @@ class LinearizableChecker(Checker):
             # report must never cost more than the verdict. A device
             # localization (explain_loc) carries the exact event already,
             # so the recovery stays purely report detail.
-            if res.final_configs is None and stream is not None \
-                    and len(stream) <= MAX_REPORT_EVENTS:
-                try:
-                    res2 = check_stream(
-                        stream, step=step_py or cas_register_step_py,
-                        init_state=init_state)
-                    if res2.valid is False:
-                        res.final_configs = res2.final_configs
-                except Exception:  # noqa: BLE001 report detail is optional
-                    logger.exception("final-configs recovery failed")
-            if res.final_configs is not None:
-                out["final-configs"] = res.final_configs
-            out["plot"] = self._render(res, history, test)
-            self._explain(out, res, history, test, stream, step_py,
-                          init_state, step_ids, explain_on, explain_loc,
-                          opts)
+            with trace.phase("settle.report"):
+                if res.final_configs is None and stream is not None \
+                        and len(stream) <= MAX_REPORT_EVENTS:
+                    try:
+                        res2 = check_stream(
+                            stream, step=step_py or cas_register_step_py,
+                            init_state=init_state)
+                        if res2.valid is False:
+                            res.final_configs = res2.final_configs
+                    except Exception:  # noqa: BLE001 report detail is optional
+                        logger.exception("final-configs recovery failed")
+                if res.final_configs is not None:
+                    out["final-configs"] = res.final_configs
+                out["plot"] = self._render(res, history, test)
+            with trace.phase("settle.explain", keys=1):
+                self._explain(out, res, history, test, stream, step_py,
+                              init_state, step_ids, explain_on,
+                              explain_loc, opts)
         return out
 
     def _trace_anomaly(self, history, op_index: int, res) -> None:

@@ -19,6 +19,7 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Iterable
 
 from jepsen_tpu import generator as gen_mod
+from jepsen_tpu import trace
 from jepsen_tpu.checker import Checker, check_safe, merge_valid
 from jepsen_tpu.generator import Generator, PENDING, as_gen
 from jepsen_tpu.utils import bounded_pmap
@@ -300,6 +301,36 @@ class IndependentChecker(Checker):
         except Exception:  # noqa: BLE001 — forensics never mask a verdict
             logger.exception("per-key anomaly forensics failed")
 
+    def _explain_keys(self, test, opts, fkeys, streams, subs, step_py,
+                      spec, invalid, results) -> None:
+        """Forensics for the batched lane's ``invalid`` keys, into their
+        ``results`` entries."""
+        import jax
+        if jax.process_count() > 1:
+            # multi-host: split the localizations across processes,
+            # allgather only the per-key positions (no witness/artifacts
+            # — every host would race on the shared store dir)
+            from jepsen_tpu.parallel.distributed import (
+                localize_keys_distributed)
+            idx = {fk: i for i, fk in enumerate(fkeys)}
+            found = localize_keys_distributed(
+                streams, [idx[fk] for fk, _, _ in invalid],
+                step_ids=spec.step_ids, step_py=step_py,
+                init_state=spec.init_state)
+            for fk, _, _ in invalid:
+                hit = found.get(idx[fk])
+                if hit is not None:
+                    results[fk]["explain"] = {
+                        "first-anomaly-op": hit[1],
+                        "backend": "matrix-bisect-distributed"}
+            return
+        for fk, stream, failure in invalid:
+            # full forensics + artifacts under the same independent/<k>
+            # lift the per-key lane uses
+            self._explain_key(test, subs[fk], stream, step_py, spec,
+                              failure, results[fk],
+                              self._key_opts(opts, fk))
+
     @staticmethod
     def _key_opts(opts, k):
         """Per-key opts: sub-checkers write under independent/<k> like the
@@ -312,16 +343,24 @@ class IndependentChecker(Checker):
                 "history-key": k}
 
     def check(self, test, history, opts):
+        with trace.phase(trace.CHECK_SPAN, ops=len(history)) as span:
+            return self._check(test, history, opts, span)
+
+    def _check(self, test, history, opts, span):
         # the per-key split rides the run's shared history IR when one
         # is attachable (memoized subhistories view): composed lifted
         # checkers split the history once, not once per checker
         from jepsen_tpu import history_ir
-        ir = history_ir.of(test, history)
-        if ir is not None:
-            from jepsen_tpu.history_ir import views
-            keys, subs = views.subhistories(ir)
-        else:
-            keys, subs = split_by_key(history)
+        with trace.phase("encode.ir", events=len(history)):
+            ir = history_ir.of(test, history)
+        with trace.phase("encode.split") as split:
+            if ir is not None:
+                from jepsen_tpu.history_ir import views
+                keys, subs = views.subhistories(ir)
+            else:
+                keys, subs = split_by_key(history)
+            split.set(keys=len(keys))
+        span.set(keys=len(keys))
         if not keys:
             return {"valid?": True, "results": {}, "count": 0}
 
@@ -391,10 +430,12 @@ class IndependentChecker(Checker):
             # per-key encode via the checker's own _encoding so the
             # initial register value interns to the kernel's init state
             # (CASRegister(0) — single-key-acid — needs init id 1)
-            encs = [chk._encoding(subs[fk]) for fk in fkeys]
-            if any(e is None for e in encs):
-                return None
-            streams = [e[0] for e in encs]
+            with trace.phase("encode.stream", keys=len(fkeys)) as span:
+                encs = [chk._encoding(subs[fk]) for fk in fkeys]
+                if any(e is None for e in encs):
+                    return None
+                streams = [e[0] for e in encs]
+                span.set(events=sum(map(len, streams)))
             step_py, spec = encs[0][1], encs[0][2]
             # accelerator=auto lets batch_check's round-trip cost model
             # route small batches to the C++/CPU lane instead of eating
@@ -439,33 +480,9 @@ class IndependentChecker(Checker):
             if invalid:
                 # per-key anomaly forensics — an invalid key is rare, so
                 # the localization dispatches stay off the happy path
-                import jax
-                if jax.process_count() > 1:
-                    # multi-host: split the localizations across
-                    # processes, allgather only the per-key positions
-                    # (no witness/artifacts — every host would race on
-                    # the shared store dir)
-                    from jepsen_tpu.parallel.distributed import (
-                        localize_keys_distributed)
-                    idx = {fk: i for i, fk in enumerate(fkeys)}
-                    found = localize_keys_distributed(
-                        streams, [idx[fk] for fk, _, _ in invalid],
-                        step_ids=spec.step_ids, step_py=step_py,
-                        init_state=spec.init_state)
-                    for fk, _, _ in invalid:
-                        hit = found.get(idx[fk])
-                        if hit is not None:
-                            results[fk]["explain"] = {
-                                "first-anomaly-op": hit[1],
-                                "backend": "matrix-bisect-distributed"}
-                else:
-                    for fk, stream, failure in invalid:
-                        # full forensics + artifacts under the same
-                        # independent/<k> lift the per-key lane uses
-                        self._explain_key(test, subs[fk], stream,
-                                          step_py, spec, failure,
-                                          results[fk],
-                                          self._key_opts(opts, fk))
+                with trace.phase("settle.explain", keys=len(invalid)):
+                    self._explain_keys(test, opts, fkeys, streams, subs,
+                                       step_py, spec, invalid, results)
             if lin_name is None:
                 return results
             pairs = list(subs.items())

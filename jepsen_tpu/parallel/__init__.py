@@ -623,25 +623,35 @@ def _scan_batch(streams, capacity, mesh, kernel, n_states):
     """The vmapped event-scan path (dense or sparse frontier kernel)."""
     import jax
 
+    from jepsen_tpu import trace
     from jepsen_tpu.checker.linear_encode import pad_streams
     from jepsen_tpu.ops.jitlin import _bucket
 
-    batch = pad_streams(streams, length=_bucket(max(len(s) for s in streams)))
-    S = max(1, batch["n_slots"])
-    if mesh is not None:
-        n_dev = mesh.devices.size
-        batch, real_b = pad_to_multiple(batch, n_dev)
-        arrays = [batch["kind"], batch["slot"], batch["f"], batch["a"], batch["b"]]
-        arrays = shard_leading(mesh, *arrays)
-    else:
-        real_b = batch["kind"].shape[0]
-        arrays = [batch["kind"], batch["slot"], batch["f"], batch["a"], batch["b"]]
+    lengths = [len(s) for s in streams]
+    with trace.phase("dispatch.pad", keys=len(streams),
+                     events=sum(lengths)) as span:
+        batch = pad_streams(streams, length=_bucket(max(lengths)))
+        S = max(1, batch["n_slots"])
+        fields = ("kind", "slot", "f", "a", "b")
+        if mesh is not None:
+            batch, real_b = pad_to_multiple(batch, mesh.devices.size)
+            arrays = shard_leading(mesh, *(batch[k] for k in fields))
+        else:
+            real_b = batch["kind"].shape[0]
+            arrays = [batch[k] for k in fields]
+        span.set(steps=int(batch["kind"].size))
 
-    fn = kernel._get(S, capacity, batched=True, num_states=n_states)
-    alive, died, ovf, peak = fn(*arrays)
-    # ONE batched host transfer: each np.asarray is a full device
-    # round-trip, so four sequential syncs would quadruple the fixed
-    # cost of every batch check
-    alive, died, ovf, peak = jax.device_get((alive, died, ovf, peak))
+    with trace.phase("dispatch.call") as span:
+        fn = kernel._get(S, capacity, batched=True, num_states=n_states)
+        compiled = fn._cache_size()
+        out = fn(*arrays)
+        # a new jitted function (a new kernel instance) or a new shape
+        # traces and lowers the scan again
+        span.set(lowered=int(fn._cache_size() > compiled))
+    with trace.phase("dispatch.readback"):
+        # ONE batched host transfer: each np.asarray is a full device
+        # round-trip, so four sequential syncs would quadruple the fixed
+        # cost of every batch check
+        alive, died, ovf, peak = jax.device_get(out)
     return [(bool(alive[i]), int(died[i]), bool(ovf[i]), int(peak[i]))
             for i in range(real_b)]

@@ -19,9 +19,9 @@ every method on the null instruments is a constant no-op. ``core.run``
 installs a live :class:`Registry` for the duration of a run (unless the
 test map sets ``metrics: False``) and restores the previous one after.
 
-Device helpers (``device_memory_stats``, ``matrix_modeled_flops``,
-``device_peak_flops``) give the checker and bench.py one shared
-vocabulary for memory high-water and roofline accounting.
+Device helpers (``device_memory_stats``, ``matrix_modeled_flops``) give
+the checker and bench.py one shared vocabulary for memory high-water
+and modeled matrix FLOPs.
 """
 from __future__ import annotations
 
@@ -672,9 +672,8 @@ def matrix_modeled_flops(n_returns: int, n_slots: int,
                          num_states: int) -> float:
     """Modeled f32 FLOPs issued by the transfer-matrix kernel for
     ``n_returns`` returns: each composes one [MV, MV] operator via
-    ~(ceil(log2 S) + 2) dense matmuls (bench.py's roofline accounting,
-    shared here so the checker's runtime gauge and bench agree; a LOWER
-    bound — the elementwise L build is excluded)."""
+    ~(ceil(log2 S) + 2) dense matmuls (bench.py's roofline accounting;
+    a LOWER bound — the elementwise L build is excluded)."""
     MV = (1 << n_slots) * num_states
     n_sq = 0
     while (1 << n_sq) < n_slots:
@@ -701,9 +700,8 @@ def matrix_phase_model(n_returns: int, n_slots: int, num_states: int,
     overhead (host prep + round trip), which the measured phase split
     attributes directly."""
     MV = (1 << n_slots) * num_states
-    # the matmul term IS the roofline numerator — shared with
-    # checker_roofline_frac so the attribution can never diverge from
-    # the fraction it explains
+    # the matmul term IS bench.py's roofline numerator, so the
+    # attribution can never diverge from the fraction it explains
     matmul = matrix_modeled_flops(n_returns, n_slots, num_states)
     lbuild = n_returns * 2.0 * n_slots * MV * MV
     combine = n_keys * n_chunks * 2.0 * MV ** 3
@@ -736,30 +734,6 @@ def combine_modeled_hbm_bytes(n_keys: int, n_chunks: int, mv: int,
         c = pairs + (c % 2)
     total += 3 * cell                   # the tot0 compose
     return n_keys * total
-
-
-_DEVICE_PEAK: dict = {}
-
-
-def set_device_peak_flops(value: float) -> None:
-    """Publishes a measured f32 matmul peak (bench.device_roofline does)
-    so runtime roofline gauges have a denominator."""
-    _DEVICE_PEAK["f32_matmul_flops"] = float(value)
-
-
-def device_peak_flops() -> float | None:
-    """Measured-or-declared f32 matmul peak: set_device_peak_flops first,
-    then the JEPSEN_DEVICE_PEAK_FLOPS env var. None means 'unknown' —
-    runtime roofline gauges are skipped, never guessed."""
-    if "f32_matmul_flops" in _DEVICE_PEAK:
-        return _DEVICE_PEAK["f32_matmul_flops"]
-    env = os.environ.get("JEPSEN_DEVICE_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            return None
-    return None
 
 
 @contextmanager

@@ -32,11 +32,18 @@ Zero-cost disabled mode, telemetry-style: the module default is
 :data:`NULL_TRACER` whose every method is a constant no-op, and call
 sites guard hot blocks on ``tracer.enabled``. ``core.run`` installs a
 live tracer per run and restores the previous one after.
+
+Checker phases (:func:`phase`) reach a third sink besides these two:
+the JAX profiler. Each phase is a ``jax.profiler.TraceAnnotation``, so
+a profiled check shows its host phases on the device ops' clock.
 """
 from __future__ import annotations
 
+import contextvars
+import itertools
 import logging
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -356,6 +363,93 @@ def use(tracer: RunTracer | NullTracer):
         yield tracer
     finally:
         install(prev)
+
+
+# ---------------------------------------------------------------------------
+# Checker phases: one span API, the profiler and the checker track as sinks
+# ---------------------------------------------------------------------------
+
+# The outermost span of one check. Phase names are ``<layer>.<phase>``
+# (``encode.ir``, ``dispatch.call``, ``settle.report``; doc/observability.md
+# "Checker phase spans"): the prefix is the layer the benchmark's
+# per-layer metrics sum over.
+CHECK_SPAN = "check"
+
+# the id of the check the current context is inside: set by the
+# outermost ``check`` phase, read by every phase nested in it (threads
+# that run part of a check start under ``contextvars.copy_context()``)
+_CHECK_ID: contextvars.ContextVar = contextvars.ContextVar(
+    "jepsen_tpu_check_id", default=None)
+_CHECK_IDS = itertools.count(1)
+
+_ANNOTATION = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None while jax is not
+    imported: no profiler can be running then, and this module must not
+    pull jax into processes (the interpreter's) that never use it."""
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+class Phase:
+    """The handle :func:`phase` yields: ``set(**stats)`` adds stats
+    known only at exit (a count, an outcome)."""
+
+    __slots__ = ("stats", "_annotation")
+
+    def __init__(self, stats: dict, annotation=None):
+        self.stats = stats
+        self._annotation = annotation
+
+    def set(self, **stats) -> None:
+        self.stats.update(stats)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**stats)
+
+
+@contextmanager
+def phase(name: str, **stats):
+    """One checker phase: a ``jax.profiler.TraceAnnotation`` named
+    ``name`` carrying ``stats``, and, while the installed tracer is
+    enabled, the same slice as an ``X`` event on :data:`TRACK_CHECKER`.
+
+    Every phase carries the stat ``check``, the id of the check it is
+    part of: the outermost :data:`CHECK_SPAN` phase takes a new id from
+    a process counter; a ``check`` phase inside another is no span at
+    all (the outer one already covers it). Costs a few microseconds
+    with the profiler and the tracer off."""
+    token = None
+    cid = _CHECK_ID.get()
+    if name == CHECK_SPAN:
+        if cid is not None:
+            yield Phase(stats)
+            return
+        cid = next(_CHECK_IDS)
+        token = _CHECK_ID.set(cid)
+    if cid is not None:
+        stats["check"] = cid
+    ann_cls = _trace_annotation()
+    ann = ann_cls(name, **stats) if ann_cls is not None else None
+    tracer = _TRACER
+    t0 = now_us() if tracer.enabled else 0
+    handle = Phase(stats, ann)
+    if ann is not None:
+        ann.__enter__()
+    try:
+        yield handle
+    finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if tracer.enabled:
+            tracer.complete(TRACK_CHECKER, name, t0, now_us() - t0,
+                            args=handle.stats)
+        if token is not None:
+            _CHECK_ID.reset(token)
 
 
 # ---------------------------------------------------------------------------

@@ -80,33 +80,36 @@ class FlightRecorder:
         expanded to full events) — flushed and fsynced (this file is
         written precisely when the process may be about to die).
         Appends, so a stall dump followed by a crash dump keeps both.
+        The dump is built in memory and written with one ``write``, so
+        a process killed mid-dump leaves no header without its events.
         Returns True on success; never raises."""
         events = self.snapshot()
         try:
             p = Path(path)
             p.parent.mkdir(parents=True, exist_ok=True)
-            with open(p, "a", encoding="utf-8") as f:
-                f.write(json.dumps({
-                    "flight_recorder": True, "reason": reason,
-                    "dumped_at": time.time(), "capacity": self.capacity,
-                    "retained": len(events),
-                    "timebase": ("wall-us" if self.op_origin_us is not None
-                                 else "relative-us"),
-                }) + "\n")
-                # a dispatch (B) tuple whose op later completed inside
-                # the ring is subsumed by its X slice — keep B only for
-                # ops still in flight (the context a crash dump is FOR)
-                completed = {(ev[1], ev[3]) for ev in events
-                             if isinstance(ev, tuple) and len(ev) == 4}
-                for ev in events:
-                    if isinstance(ev, tuple):
-                        if ev[0] == OP_BEGIN and \
-                                (ev[1], ev[2].get("time")) in completed:
-                            continue
-                        ev = expand_op_event(ev, self.op_origin_us)
-                    if ev is None:
+            lines = [json.dumps({
+                "flight_recorder": True, "reason": reason,
+                "dumped_at": time.time(), "capacity": self.capacity,
+                "retained": len(events),
+                "timebase": ("wall-us" if self.op_origin_us is not None
+                             else "relative-us"),
+            })]
+            # a dispatch (B) tuple whose op later completed inside the
+            # ring is subsumed by its X slice — keep B only for ops
+            # still in flight (the context a crash dump is FOR)
+            completed = {(ev[1], ev[3]) for ev in events
+                         if isinstance(ev, tuple) and len(ev) == 4}
+            for ev in events:
+                if isinstance(ev, tuple):
+                    if ev[0] == OP_BEGIN and \
+                            (ev[1], ev[2].get("time")) in completed:
                         continue
-                    f.write(json.dumps(ev, default=str) + "\n")
+                    ev = expand_op_event(ev, self.op_origin_us)
+                if ev is None:
+                    continue
+                lines.append(json.dumps(ev, default=str))
+            with open(p, "a", encoding="utf-8") as f:
+                f.write("\n".join(lines) + "\n")
                 f.flush()
                 os.fsync(f.fileno())
             logger.warning("flight recorder dumped %d event(s) to %s "

@@ -495,12 +495,25 @@ def test_sigkill_leaves_loadable_trace_and_flight_dump(tmp_path):
     proc = subprocess.Popen([sys.executable, worker, str(tmp_path)],
                             stdout=subprocess.PIPE,
                             stderr=subprocess.STDOUT, text=True, env=env)
+
+    def complete_dump(path):
+        """The dump's rows once it is whole: its last line parses and
+        the stall watchdog's instant is in it; else None."""
+        try:
+            rows = [json.loads(x) for x in path.read_text().splitlines()]
+        except (OSError, ValueError):
+            return None
+        if any(r.get("ph") == "i" and r.get("name") == "stall"
+               for r in rows):
+            return rows
+        return None
+
     flight = None
     deadline = time.monotonic() + 120
     try:
         while time.monotonic() < deadline:
             dumps = list(tmp_path.glob("noop/*/flight-recorder.jsonl"))
-            if dumps:
+            if dumps and complete_dump(dumps[0]) is not None:
                 flight = dumps[0]
                 break
             if proc.poll() is not None:
@@ -525,7 +538,8 @@ def test_sigkill_leaves_loadable_trace_and_flight_dump(tmp_path):
     assert any(ev.get("ph") == "X" for ev in evs)
     assert any(ev.get("ph") == "M" for ev in evs)
     # the flight dump: header + expanded events, hung op still open
-    rows = [json.loads(x) for x in flight.read_text().splitlines()]
+    rows = complete_dump(flight)
+    assert rows is not None
     assert rows[0]["flight_recorder"] is True
     assert rows[0]["reason"] == "stall"
     assert any(r.get("ph") == "X" for r in rows[1:])
