@@ -166,10 +166,14 @@ def _prefix(stream, n_events: int):
                    op_index=stream.op_index[:n_events])
 
 
-def _device_args(batch):
+def _device_args(batch, num_states):
+    """The frontier scan's arguments for a one-stream padded batch."""
     import jax
-    return tuple(jax.numpy.asarray(batch[k][0])
-                 for k in ("kind", "slot", "f", "a", "b"))
+
+    from jepsen_tpu.ops.jitlin import scan_inputs
+    args, _ = scan_inputs(*(batch[k] for k in ("kind", "slot", "f", "a", "b")),
+                          max(1, batch["n_slots"]), num_states)
+    return tuple(jax.numpy.asarray(x[0]) for x in args)
 
 
 def _force(*xs):
@@ -808,7 +812,7 @@ def cfg_matrix_kernel():
 
     batch = pad_streams([stream], length=_bucket(E))
     run = JitLinKernel()._get(S, CAPACITY, batched=False, num_states=V)
-    args = _device_args(batch)
+    args = _device_args(batch, V)
     _warm_timed("matrix_kernel_scan",             # warm-up compile
                 lambda: _force(*run(*args)))
     out, t_scan = _trials(lambda: _force(*run(*args)), 5)
@@ -846,7 +850,7 @@ def cfg_matrix_kernel():
         mb = matrix_check(bad)
         assert mb is not None and not mb[0]
         batch_bad = pad_streams([bad], length=_bucket(E))
-        alive_b, _, _, _ = _force(*run(*_device_args(batch_bad)))
+        alive_b, _, _, _ = _force(*run(*_device_args(batch_bad, V)))
         dt_fail = time.perf_counter() - t0
         assert not bool(alive_b)
         extra["failing_double_run_seconds"] = round(dt_fail, 3)
@@ -2137,7 +2141,7 @@ def cfg_headline() -> float:
     S = max(1, batch["n_slots"])
     run = JitLinKernel()._get(S, CAPACITY, batched=False,
                               num_states=len(stream.intern))
-    args = _device_args(batch)
+    args = _device_args(batch, len(stream.intern))
     _warm_timed("headline_scan", lambda: _force(*run(*args)))
     out, scan_times = _trials(lambda: _force(*run(*args)), 5)
     alive, died, ovf, peak = out

@@ -5,22 +5,29 @@ jepsen/src/jepsen/checker.clj:199-203) with a fixed-shape XLA program:
 
 * A *configuration* is (mask, state): ``mask`` = bitset over pending-op
   slots that have already been linearized; ``state`` = interned model state.
-* The frontier of live configurations is a capacity-K array pair.
-* Events stream through a ``lax.scan``: invokes update the per-slot op
-  table; before consuming each return, the closure of the frontier under
-  "linearize any pending, unlinearized op" is computed by masked batched
-  expansion ([K, S] candidate grid through the model's int transition) and
-  sort-based dedup (two lexicographic ``lax.sort`` passes), then configs
-  that failed to linearize the returning op are killed.
+* Sparse kernel (``_build_step``): the frontier of live configurations is
+  a capacity-K array pair, and events stream through a ``lax.scan``:
+  invokes update the per-slot op table; before consuming each return, the
+  closure of the frontier under "linearize any pending, unlinearized op"
+  is computed by masked batched expansion ([K, S] candidate grid through
+  the model's int transition) and sort-based dedup (two lexicographic
+  ``lax.sort`` passes), then configs that failed to linearize the
+  returning op are killed. The frontier is monotone within a closure, so
+  convergence is detected by count; overflow beyond K makes a False
+  verdict "unknown" (a surviving subset is still a sound witness for
+  True).
+* Dense kernel (``_build_dense_step``, small 2^S x V spaces): the
+  frontier is an exact boolean table, and the scan steps over *returns*,
+  not events: the pending sets and op tables depend on the event stream
+  alone, so a host pre-pass (``scan_inputs``) hands each return step its
+  pending set and op table, and every step runs one closure and one
+  kill, with no per-event branch.
 
-The frontier is monotone within a closure, so convergence is detected by
-count; overflow beyond K makes a False verdict "unknown" (a surviving
-subset is still a sound witness for True). The whole kernel vmaps over a
-batch of per-key histories — the jepsen.independent -> vmap mapping
-(SURVEY.md §2.6, BASELINE config 3).
+Both kernels vmap over a batch of per-key histories — the
+jepsen.independent -> vmap mapping (SURVEY.md §2.6, BASELINE config 3).
 
-Shapes are static in (E, S, K): pad E via linear_encode.pad_streams and
-bucket history lengths so XLA caches compilations.
+Shapes are static in (E or R, S, K|V): pad E via linear_encode.pad_streams
+and bucket history lengths (return counts) so XLA caches compilations.
 """
 from __future__ import annotations
 
@@ -260,6 +267,13 @@ def _build_dense_step(num_slots: int, num_states: int, step_ids,
     the MXU with f32 accumulation) OR-reduced into T, iterated to a
     fixpoint. No sorts, no dedup, and — because the table covers the
     whole space — no capacity overflow: the verdict is always exact.
+
+    The scan steps over returns (``scan_inputs``' dense form): each step
+    builds its [S, V, V] slot matrices from the return's op table, runs
+    the closure and the kill, and a padding step (slot -1) leaves the
+    carry as it is. Invokes and no-ops cost no step, so under ``vmap``
+    — where a batched ``lax.switch`` would run every branch at every
+    step — a key pays one closure per return, not one per padded event.
     """
     import jax
     import jax.numpy as jnp
@@ -284,8 +298,13 @@ def _build_dense_step(num_slots: int, num_states: int, step_ids,
         mt = ok[:, None] & (st2[:, None] == v_range[None, :])
         return mt.astype(jnp.bfloat16), oob  # [V, V]
 
-    def closure(table, pend_mask, mt):
-        pend = ((pend_mask >> jnp.arange(S, dtype=jnp.uint32)) & 1).astype(bool)
+    def slot_matrices(ops, pend):
+        """The op table's [S, V, V] slot matrices, and whether a pending
+        slot's transition leaves the intern range."""
+        mt, oob = jax.vmap(slot_matrix)(ops[:, 0], ops[:, 1], ops[:, 2])
+        return mt, (oob & pend).any()
+
+    def closure(table, pend, mt, go):
         gate = pend[:, None] & has_bit  # [S, M]: rows that may receive via t
 
         def body(carry):
@@ -301,70 +320,61 @@ def _build_dense_step(num_slots: int, num_states: int, step_ids,
             _, changed, it = carry
             return changed & (it < S)
 
+        # ``go`` false (a padding step) runs no iteration at all
         table, _, _ = lax.while_loop(
-            cond, body, (table, jnp.bool_(True), jnp.int32(0)))
+            cond, body, (table, go, jnp.int32(0)))
         return table
 
-    def step_event(carry, ev):
-        table, mt, pend_mask, alive, died_at, peak, inexact, eidx = carry
-        kind, slot, f, a, b = ev
-        slot_bit = jnp.uint32(1) << slot.astype(jnp.uint32)
+    def step_return(carry, ret):
+        table, alive, died_at, peak, inexact, ridx = carry
+        slot, pend, ops = ret
+        is_ret = slot >= 0
+        mt, oob = slot_matrices(ops, pend)
+        tc = closure(table, pend, mt, is_ret)
+        # keep configs that linearized the returning op, clearing its
+        # bit: T'[r] = (s not in r) & Tc[r | bit_s]
+        s = jnp.maximum(slot, 0)
+        t2 = jnp.where(~has_bit[s][:, None], tc[xor_idx[s]], False)
+        t2 = jnp.where(is_ret, t2, table)
+        now_alive = t2.any()
+        new_died = jnp.where(alive & ~now_alive, ridx, died_at)
+        count = jnp.sum(tc.astype(jnp.int32))
+        peak = jnp.where(is_ret, jnp.maximum(peak, count), peak)
+        return (t2, alive & now_alive, new_died, peak, inexact | oob,
+                ridx + 1), None
 
-        def on_invoke(_):
-            # only this slot's [V, V] transition block changes — the rest
-            # of mt rides the carry untouched
-            m_slot, oob = slot_matrix(f, a, b)
-            return (table, mt.at[slot].set(m_slot), pend_mask | slot_bit,
-                    alive, died_at, peak, inexact | oob, eidx + 1)
+    def scan_from(table0, slot, pend, ops, tail_pend, tail_ops):
+        carry = (table0, jnp.bool_(True), jnp.int32(-1), jnp.int32(1),
+                 jnp.bool_(False), jnp.int32(0))
+        carry, _ = lax.scan(step_return, carry,
+                            (slot.astype(jnp.int32), pend.astype(bool),
+                             ops.astype(jnp.int32)))
+        table, alive, died_at, peak, inexact, _ = carry
+        # ops invoked after the last return enter no closure, but their
+        # out-of-range flags count as every invoked op's do
+        _, tail_oob = slot_matrices(tail_ops.astype(jnp.int32),
+                                    tail_pend.astype(bool))
+        return table, alive, died_at, peak, inexact | tail_oob
 
-        def on_return(_):
-            tc = closure(table, pend_mask, mt)
-            # keep configs that linearized the returning op, clearing its
-            # bit: T'[r] = (s not in r) & Tc[r | bit_s]
-            hasb = has_bit[slot]          # [M]
-            t2 = jnp.where(~hasb[:, None], tc[xor_idx[slot]], False)
-            now_alive = t2.any()
-            new_died = jnp.where(alive & ~now_alive, eidx, died_at)
-            count = jnp.sum(tc.astype(jnp.int32))
-            return (t2, mt, pend_mask & ~slot_bit, alive & now_alive,
-                    new_died, jnp.maximum(peak, count), inexact, eidx + 1)
-
-        def on_noop(_):
-            return (table, mt, pend_mask, alive, died_at, peak, inexact,
-                    eidx + 1)
-
-        return lax.switch(kind, [on_invoke, on_return, on_noop], None), None
-
-    def scan_from(table0, events):
-        carry = (
-            table0,
-            jnp.zeros((S, V, V), jnp.bfloat16),
-            jnp.uint32(0), jnp.bool_(True), jnp.int32(-1), jnp.int32(1),
-            jnp.bool_(False), jnp.int32(0),
-        )
-        carry, _ = lax.scan(step_event, carry, events)
-        (table, _, _, alive, died_at, peak, inexact, _) = carry
-        return table, alive, died_at, peak, inexact
-
-    def run(kind, slot, f, a, b):
+    def run(slot, pend, ops, tail_pend, tail_ops):
+        """One step per return (``scan_inputs``' dense form); the died
+        index is the return step."""
         table0 = jnp.zeros((M, V), dtype=bool).at[0, init_state].set(True)
-        events = (kind.astype(jnp.int32), slot.astype(jnp.int32),
-                  f.astype(jnp.int32), a.astype(jnp.int32), b.astype(jnp.int32))
-        _, alive, died_at, peak, inexact = scan_from(table0, events)
+        _, alive, died_at, peak, inexact = scan_from(
+            table0, slot, pend, ops, tail_pend, tail_ops)
         # the table covers the whole config space, so the only inexactness
         # is a state id escaping the intern range — surfaced on the
         # overflow channel so verdict() degrades to unknown, not wrong
         return alive, died_at, inexact, peak
 
-    def run_resume(kind, slot, f, a, b, table0):
+    def run_resume(slot, pend, ops, tail_pend, tail_ops, table0):
         """Segmented-verification variant: starts from a caller-supplied
         frontier table (a previous segment's output — the stream must be
-        cut at quiescent points, i.e. no ops pending across the cut) and
-        returns the final table alongside the verdict, staying on device
-        between segments."""
-        events = (kind.astype(jnp.int32), slot.astype(jnp.int32),
-                  f.astype(jnp.int32), a.astype(jnp.int32), b.astype(jnp.int32))
-        table, alive, died_at, peak, inexact = scan_from(table0, events)
+        cut at quiescent points, i.e. no ops pending across the cut, so
+        each segment's op table starts empty) and returns the final table
+        alongside the verdict, staying on device between segments."""
+        table, alive, died_at, peak, inexact = scan_from(
+            table0, slot, pend, ops, tail_pend, tail_ops)
         return alive, died_at, inexact, peak, table
 
     def init_table():
@@ -377,51 +387,112 @@ def _build_dense_step(num_slots: int, num_states: int, step_ids,
     return run
 
 
-def _returns_prepass(kind, slot, f, a, b):
-    """Host pre-pass for the matrix kernel: the per-slot op table and
+def _returns_prepass_batch(kind, slot, f, a, b, S: int, r_pad: int):
+    """Host pre-pass over a [B, E] event batch: the per-slot op table and
     pending mask evolve deterministically from the event stream alone
     (invokes/returns), independent of the frontier — so each return's
     (pending set, op table, returning slot) is computable up front.
 
-    Fully vectorized (O(S) passes of O(E) numpy work, no per-event Python)
-    so the prepass doesn't dominate the kernel it feeds: per slot t, the
-    pending bit at event i is ``#invokes(t) <= i  >  #returns(t) <= i``
-    (cumulative counts), and the current op is the last invoke of t at or
-    before i, found by searchsorted into t's invoke positions.
+    Fully vectorized (numpy passes over [B, S, E], no per-event or
+    per-key Python) so the prepass doesn't dominate the kernel it feeds:
+    per slot t, the pending bit at a return i is ``last invoke of t <= i
+    > last return of t < i`` (running maxima of t's positions), and the
+    current op is t's last invoke.
 
-    Returns numpy arrays over the R return events."""
+    Returns, over each key's returns padded to ``r_pad``: the returning
+    slot (-1 past the key's last return), the pending set [S], the op
+    table [S, 3] and the return's event index (-1 past the last); then
+    the pending set and op table after the key's last event (the ops
+    still pending at its end)."""
     kind = np.asarray(kind)
     slot = np.asarray(slot)
-    fabs = np.stack([np.asarray(f, np.int64), np.asarray(a, np.int64),
-                     np.asarray(b, np.int64)], axis=1)
-    S = int(slot.max(initial=0)) + 1
-    ret_idx = np.nonzero(kind == EV_RETURN)[0]
-    R = ret_idx.shape[0]
-    if R == 0:
-        return (np.zeros((0,), np.int32), np.zeros((0, S), bool),
-                np.zeros((0, S, 3), np.int64), S)
-    r_slot = slot[ret_idx].astype(np.int32)
-    r_pend = np.zeros((R, S), bool)
-    r_ops = np.zeros((R, S, 3), np.int64)
+    B, E = kind.shape
+    # [B, 1 + E, 3]: row 0 is op 0, the op of a slot not yet invoked
+    fabs = np.stack([np.asarray(f), np.asarray(a), np.asarray(b)], axis=-1)
+    fabs = np.concatenate([np.zeros((B, 1, 3), fabs.dtype), fabs], axis=1)
     is_inv = kind == EV_INVOKE
     is_ret = kind == EV_RETURN
-    for t in range(S):
-        on_t = slot == t
-        inv_pos = np.nonzero(is_inv & on_t)[0]
-        # pending at return event i: invokes-so-far > returns-so-far,
-        # where "so-far" includes event i itself (a return of slot t at i
-        # still sees t pending — it is the op being linearized-and-killed)
-        n_inv = np.cumsum(is_inv & on_t)
-        n_ret_before = np.cumsum(is_ret & on_t) - (is_ret & on_t)
-        r_pend[:, t] = (n_inv > n_ret_before)[ret_idx]
-        if inv_pos.size == 0:
-            continue  # slot never invoked: never pending, op stays 0
-        # current op of slot t at event i: last invoke of t at or before i
-        j = np.searchsorted(inv_pos, ret_idx, side="right") - 1
-        has = j >= 0
-        src = inv_pos[np.where(has, j, 0)]
-        r_ops[:, t, :] = np.where(has[:, None], fabs[src], 0)
-    return r_slot, r_pend, r_ops, S
+    # (key, event) of every return, key-major, and its rank in its key
+    kb, ke = np.nonzero(is_ret)
+    kr = np.cumsum(is_ret, axis=1)[kb, ke] - 1
+    r_slot = np.full((B, r_pad), -1, np.int32)
+    r_slot[kb, kr] = slot[kb, ke]
+    ret_event = np.full((B, r_pad), -1, np.int64)
+    ret_event[kb, kr] = ke
+    r_pend = np.zeros((B, r_pad, S), bool)
+    r_ops = np.zeros((B, r_pad, S, 3), fabs.dtype)
+    if E == 0:
+        return (r_slot, r_pend, r_ops, ret_event, np.zeros((B, S), bool),
+                np.zeros((B, S, 3), fabs.dtype))
+    # every slot at once: [B, S, E] one-hot invokes and returns
+    on = slot[:, None, :] == np.arange(S, dtype=slot.dtype)[None, :, None]
+    pos = np.arange(1, E + 1, dtype=np.int32)
+
+    def last(mask):
+        """1-based position of each row's latest True at or before each
+        event (0: none yet)."""
+        return np.maximum.accumulate(np.where(mask, pos, 0), axis=2)
+
+    inv = last(on & is_inv[:, None, :])
+    ret = last(on & is_ret[:, None, :])
+    # an invoke sets its slot pending and a return clears it; a return
+    # sees the pending set from before it, its own slot included (the op
+    # being linearized-and-killed)
+    before = np.concatenate([np.zeros((B, S, 1), np.int32), ret[..., :-1]],
+                            axis=2)
+    r_pend[kb, kr] = (inv > before)[kb, :, ke]
+    tail_pend = inv[..., -1] > ret[..., -1]
+    # current op of slot t: its last invoke, as a row of fabs (0 where t
+    # was never invoked)
+    r_ops[kb, kr] = fabs[kb[:, None], inv[kb, :, ke]]
+    tail_ops = fabs[np.arange(B)[:, None], inv[..., -1]]
+    return r_slot, r_pend, r_ops, ret_event, tail_pend, tail_ops
+
+
+def _returns_prepass(kind, slot, f, a, b):
+    """The matrix kernel's pre-pass: :func:`_returns_prepass_batch` of
+    one stream. Returns numpy arrays over its R return events (returning
+    slot, pending set, op table) and the slot count S."""
+    kind = np.asarray(kind)
+    slot = np.asarray(slot)
+    S = int(slot.max(initial=0)) + 1
+    R = int((kind == EV_RETURN).sum())
+    r_slot, r_pend, r_ops, *_ = _returns_prepass_batch(
+        kind[None], slot[None], np.asarray(f)[None], np.asarray(a)[None],
+        np.asarray(b)[None], S, R)
+    return r_slot[0], r_pend[0], r_ops[0], S
+
+
+def scan_inputs(kind, slot, f, a, b, S: int, num_states: int | None):
+    """The frontier scan's inputs for a padded [B, E] event batch, and
+    the map from the scan's step index back to the event index (None:
+    they are the same). The sparse kernel steps over the events as they
+    are. The dense kernel steps over returns: each key's returns, padded
+    to ``_bucket(R_max, floor=16)`` steps with returning slot -1, with
+    their pending sets and op tables, plus the ops still pending at the
+    key's end (their out-of-range flags count, though no closure sees
+    them)."""
+    if not _dense_ok(S, num_states):
+        return (kind, slot, f, a, b), None
+    kind = np.asarray(kind)
+    R_max = int((kind == EV_RETURN).sum(axis=1).max(initial=0))
+    r_slot, r_pend, r_ops, ret_event, tail_pend, tail_ops = \
+        _returns_prepass_batch(kind, slot, f, a, b, S,
+                               _bucket(R_max, floor=16))
+    return ((r_slot, r_pend, r_ops.astype(np.int32), tail_pend,
+             tail_ops.astype(np.int32)), ret_event)
+
+
+def died_events(died, ret_event):
+    """The scan's died step(s) as event indices: ``died`` [B] (or a
+    scalar, with ``ret_event`` [R]) through :func:`scan_inputs`' map;
+    -1 stays -1."""
+    died = np.asarray(died)
+    if ret_event is None:
+        return died
+    idx = np.maximum(died, 0)[..., None]
+    ev = np.take_along_axis(ret_event, idx, axis=-1)[..., 0]
+    return np.where(died >= 0, ev, -1)
 
 
 def receiver_kill_tables(S: int, V: int):
@@ -1938,11 +2009,14 @@ def segmented_check(stream, max_segment: int = 1 << 21, kernel=None,
             continue  # already covered by the resumed carry
         seg = _slice_stream(stream, base, end)
         batch = pad_streams([seg], length=_bucket(len(seg)))
-        out = run(batch["kind"][0], batch["slot"][0], batch["f"][0],
-                  batch["a"][0], batch["b"][0], *carry)
+        args, ret_event = scan_inputs(
+            *(batch[k] for k in ("kind", "slot", "f", "a", "b")), S,
+            num_states)
+        out = run(*(x[0] for x in args), *carry)
         a, d, o, p = out[0], out[1], out[2], out[3]
         carry = out[4:]
-        a, d, o, p = (bool(np.asarray(a)), int(np.asarray(d)),
+        d = died_events(d, None if ret_event is None else ret_event[0])
+        a, d, o, p = (bool(np.asarray(a)), int(d),
                       bool(np.asarray(o)), int(np.asarray(p)))
         ovf |= o
         peak = max(peak, p)
@@ -1993,7 +2067,8 @@ class JitLinKernel:
     def _get(self, S: int, K: int, batched: bool, num_states: int | None = None,
              resume: bool = False):
         """Picks the dense exact kernel when the configuration space is
-        small enough, else the capacity-K sort-based frontier. With
+        small enough, else the capacity-K sort-based frontier; either
+        takes :func:`scan_inputs`' arrays for its (S, num_states). With
         ``resume`` the returned callable takes and returns the frontier
         carry (dense: +table; sparse: +mask,state) for segmented
         verification; it also exposes ``.init_carry()``."""

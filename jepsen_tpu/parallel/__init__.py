@@ -620,26 +620,33 @@ def _cpu_batch_maybe(streams, kernel, force: bool = False):
 
 
 def _scan_batch(streams, capacity, mesh, kernel, n_states):
-    """The vmapped event-scan path (dense or sparse frontier kernel)."""
+    """The vmapped frontier-scan path: the dense kernel over each key's
+    returns, the sparse one over its events (jitlin.scan_inputs)."""
     import jax
 
     from jepsen_tpu import trace
     from jepsen_tpu.checker.linear_encode import pad_streams
-    from jepsen_tpu.ops.jitlin import _bucket
+    from jepsen_tpu.ops.jitlin import (EV_RETURN, _bucket, died_events,
+                                       scan_inputs)
 
     lengths = [len(s) for s in streams]
     with trace.phase("dispatch.pad", keys=len(streams),
                      events=sum(lengths)) as span:
         batch = pad_streams(streams, length=_bucket(max(lengths)))
         S = max(1, batch["n_slots"])
-        fields = ("kind", "slot", "f", "a", "b")
         if mesh is not None:
             batch, real_b = pad_to_multiple(batch, mesh.devices.size)
-            arrays = shard_leading(mesh, *(batch[k] for k in fields))
         else:
             real_b = batch["kind"].shape[0]
-            arrays = [batch[k] for k in fields]
-        span.set(steps=int(batch["kind"].size))
+        arrays, ret_event = scan_inputs(
+            *(batch[k] for k in ("kind", "slot", "f", "a", "b")), S,
+            n_states)
+        if mesh is not None:
+            arrays = shard_leading(mesh, *arrays)
+        # steps: the scan steps of the whole batch, padding included;
+        # returns: the real return steps among them
+        span.set(steps=int(arrays[0].size),
+                 returns=int((batch["kind"] == EV_RETURN).sum()))
 
     with trace.phase("dispatch.call") as span:
         fn = kernel._get(S, capacity, batched=True, num_states=n_states)
@@ -653,5 +660,6 @@ def _scan_batch(streams, capacity, mesh, kernel, n_states):
         # round-trip, so four sequential syncs would quadruple the fixed
         # cost of every batch check
         alive, died, ovf, peak = jax.device_get(out)
+    died = died_events(died, ret_event)
     return [(bool(alive[i]), int(died[i]), bool(ovf[i]), int(peak[i]))
             for i in range(real_b)]

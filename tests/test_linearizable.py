@@ -201,7 +201,8 @@ def test_dense_and_sparse_kernels_agree():
     capacity-K sort-based frontier must return identical verdicts; the
     batch path auto-selects dense, so pin each explicitly here."""
     import jax
-    from jepsen_tpu.ops.jitlin import (JitLinKernel, _bucket, verdict)
+    from jepsen_tpu.ops.jitlin import (JitLinKernel, _bucket, scan_inputs,
+                                       verdict)
     from jepsen_tpu.checker.linear_encode import pad_streams
 
     kernel = JitLinKernel()
@@ -213,12 +214,14 @@ def test_dense_and_sparse_kernels_agree():
         stream = encode_register_ops(h)
         batch = pad_streams([stream], length=_bucket(len(stream)))
         S = max(1, batch["n_slots"])
-        args = tuple(batch[k][0] for k in ("kind", "slot", "f", "a", "b"))
+        events = tuple(batch[k] for k in ("kind", "slot", "f", "a", "b"))
         dense = kernel._get(S, 128, batched=False,
                             num_states=len(stream.intern))
         sparse = kernel._get(S, 128, batched=False, num_states=None)
-        da, _, dovf, _ = map(jax.device_get, dense(*args))
-        sa, _, sovf, _ = map(jax.device_get, sparse(*args))
+        d_args, _ = scan_inputs(*events, S, len(stream.intern))
+        s_args, _ = scan_inputs(*events, S, None)
+        da, _, dovf, _ = map(jax.device_get, dense(*(x[0] for x in d_args)))
+        sa, _, sovf, _ = map(jax.device_get, sparse(*(x[0] for x in s_args)))
         assert not bool(dovf)  # dense is exact, never overflows
         assert verdict(bool(da), bool(dovf)) == verdict(bool(sa), bool(sovf)), \
             f"trial {trial}: dense={bool(da)} sparse={bool(sa)}\n{h}"
@@ -233,14 +236,15 @@ def _scan_alive(history):
     import jax
     from jepsen_tpu.checker.linear_encode import (encode_register_ops,
                                                   pad_streams)
-    from jepsen_tpu.ops.jitlin import JitLinKernel, _bucket
+    from jepsen_tpu.ops.jitlin import JitLinKernel, _bucket, scan_inputs
     stream = encode_register_ops(history)
     batch = pad_streams([stream], length=_bucket(len(stream)))
-    run = JitLinKernel()._get(max(1, batch["n_slots"]), 256, batched=False,
+    S = max(1, batch["n_slots"])
+    run = JitLinKernel()._get(S, 256, batched=False,
                               num_states=len(stream.intern))
-    args = tuple(jax.numpy.asarray(batch[k][0])
-                 for k in ("kind", "slot", "f", "a", "b"))
-    alive, _, _, _ = run(*args)
+    args, _ = scan_inputs(*(batch[k] for k in ("kind", "slot", "f", "a", "b")),
+                          S, len(stream.intern))
+    alive, _, _, _ = run(*(jax.numpy.asarray(x[0]) for x in args))
     return bool(alive)
 
 

@@ -117,6 +117,26 @@ def test_graft_entry_scan_step_compiles(one_chip):
     assert jax.default_backend() == "cpu"   # the described chip ran nothing
 
 
+@pytest.mark.parametrize("B,R", [(1, 8192), (512, 32)])
+def test_dense_return_scan_compiles(one_chip, B, R):
+    """The batched dense frontier scan at the register cells' shapes:
+    S = 10 slots, 16 states, one 10k-op history (8,192 padded return
+    steps) or 512 keys of at most 20 ops (32 steps each)."""
+    import jax.numpy as jnp
+
+    from jepsen_tpu.ops.jitlin import JitLinKernel
+
+    S = 10
+    fn = JitLinKernel()._get(S, 256, batched=True, num_states=16)
+    hlo = _compile(fn, _sds(one_chip, (B, R), jnp.int32),
+                   _sds(one_chip, (B, R, S), jnp.bool_),
+                   _sds(one_chip, (B, R, S, 3), jnp.int32),
+                   _sds(one_chip, (B, S), jnp.bool_),
+                   _sds(one_chip, (B, S, 3), jnp.int32))
+    assert hlo.startswith("HloModule jit_run")
+    assert "tpu_custom_call" not in hlo
+
+
 @pytest.fixture(scope="module")
 def batch_64x1k():
     """The matrix kernel and one sub-batch of grids at the 64-key x 1k-op

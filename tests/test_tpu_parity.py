@@ -74,7 +74,8 @@ def test_event_scan_verdict_parity(tpu_device, streams):
     """Dense-table event-scan kernel vs the CPU oracle, both verdicts."""
     from jepsen_tpu.checker.linear_cpu import check_stream
     from jepsen_tpu.checker.linear_encode import pad_streams
-    from jepsen_tpu.ops.jitlin import JitLinKernel, _bucket, verdict
+    from jepsen_tpu.ops.jitlin import (JitLinKernel, _bucket, scan_inputs,
+                                       verdict)
 
     good, bad = streams
     for stream, want in ((good, True), (bad, False)):
@@ -82,9 +83,11 @@ def test_event_scan_verdict_parity(tpu_device, streams):
         run = JitLinKernel()._get(stream.n_slots, 256, batched=False,
                                   num_states=len(stream.intern))
         import jax.numpy as jnp
-        args = tuple(jnp.asarray(batch[k][0])
-                     for k in ("kind", "slot", "f", "a", "b"))
-        alive, died, ovf, _peak = [np.asarray(x) for x in run(*args)]
+        args, _ = scan_inputs(
+            *(batch[k] for k in ("kind", "slot", "f", "a", "b")),
+            stream.n_slots, len(stream.intern))
+        alive, died, ovf, _peak = [
+            np.asarray(x) for x in run(*(jnp.asarray(a[0]) for a in args))]
         assert verdict(bool(alive), bool(ovf)) is want
         assert check_stream(stream).valid is want
 
