@@ -1,8 +1,14 @@
 """jepsen.independent registers through
-``independent.checker(LinearizableChecker(accelerator="tpu"))``."""
+``independent.checker(LinearizableChecker(accelerator="tpu"))``: keyed
+histories from the register generator (traffic.py), each key held to
+the cas-register reference (reference.py)."""
 from __future__ import annotations
 
-from benchmark import reference
+from benchmark import reference as plain
+from benchmark import traffic
+
+mix = traffic.load_mix
+history = traffic.make_history
 
 
 def check(history: list[dict], test: dict) -> dict:
@@ -27,11 +33,12 @@ def answer(result: dict, history: list[dict]) -> dict:
     return out
 
 
-def reference_keys(history: list[dict]) -> dict:
-    return reference.split_keys(history)
-
-
-def reference_answer(verdicts: dict) -> dict:
+def reference(history: list[dict], which: str) -> dict:
+    """The answer of the plain reference (``which="reference"``) or of
+    the control (``"control"``) on each key, and lifted, as ``answer``
+    gives it."""
+    check = plain.CHECKS[which]
+    verdicts = {k: check(h) for k, h in plain.split_keys(history).items()}
     out = {str(k): (v.valid, v.failed_at) for k, v in verdicts.items()}
     out["*"] = (all(v.valid for v in verdicts.values()),
                 frozenset(k for k, (valid, _) in out.items() if not valid))

@@ -1,5 +1,13 @@
-"""A single register through ``LinearizableChecker(accelerator="tpu")``."""
+"""A single register through ``LinearizableChecker(accelerator="tpu")``:
+histories from the register generator (traffic.py), answers held to the
+cas-register reference (reference.py)."""
 from __future__ import annotations
+
+from benchmark import reference as plain
+from benchmark import traffic
+
+mix = traffic.load_mix
+history = traffic.make_history
 
 
 def check(history: list[dict], test: dict) -> dict:
@@ -31,10 +39,8 @@ def answer(result: dict, history: list[dict]) -> dict:
     return {None: (valid, at if first == at else -3)}
 
 
-def reference_keys(history: list[dict]) -> dict:
-    return {None: history}
-
-
-def reference_answer(verdicts: dict) -> dict:
-    v = verdicts[None]
+def reference(history: list[dict], which: str) -> dict:
+    """The answer of the plain reference (``which="reference"``) or of
+    the control (``"control"``), as ``answer`` gives it."""
+    v = plain.CHECKS[which](history)
     return {None: (v.valid, v.failed_at)}

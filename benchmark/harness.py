@@ -2,10 +2,26 @@
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: the
 cell's configuration in ``configs/<config>.json``, its traffic mix in
-``traffic/<traffic>.json``, the way its checker is driven in
-``checkers/<checker>.py`` (named by the configuration) and each metric's
-reader in ``metrics/<metric>.py``. Adding a cell, a configuration or a
-metric adds files; this module does not change.
+``traffic/<traffic>.json``, the configuration's kind of history in
+``checkers/<checker>.py`` (named by the configuration's ``checker`` key)
+and each metric's reader in ``metrics/<metric>.py``. Adding a cell, a
+configuration or a metric adds files; this module does not change.
+
+The checker module is the one place that knows its kind of history.
+It provides:
+
+* ``mix(config, path)``: the traffic mix of the file ``path`` under the
+  configuration; the harness reads only its ``pool`` (histories in the
+  pool) and ``test`` (the test map each check is given);
+* ``history(mix, seed, j)``: history ``j`` of the pool, made from the
+  seed, as a ``traffic.Planted`` (the history and its plants, each a
+  ``(key, kind, index)``);
+* ``check(history, test)``: one check through the program under test;
+* ``answer(result, history)``: the check's answer, a dict from key to
+  ``(valid, report)`` (see compare.py);
+* ``reference(history, which)``: the plain reference's answer
+  (``which="reference"``) or the control's (``which="control"``), in
+  the shape ``answer`` returns.
 
 A run:
 
@@ -36,7 +52,7 @@ from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from pathlib import Path
 
-from benchmark import compare, reference, traffic
+from benchmark import compare
 
 HERE = Path(__file__).resolve().parent
 REPO = HERE.parent
@@ -75,19 +91,22 @@ class Bench:
         return json.loads((self.root / "configs" / f"{name}.json")
                           .read_text())
 
-    def mix(self, cell: dict) -> traffic.Mix:
+    def mix(self, cell: dict):
         tdir = self.traffic_dir or self.root / "traffic"
-        return traffic.load_mix(self.config(cell["config"]),
-                                tdir / f"{cell['traffic']}.json")
+        return self.checker(cell).mix(self.config(cell["config"]),
+                                      tdir / f"{cell['traffic']}.json")
 
     def _module(self, kind: str, name: str):
-        """``<root>/<kind>/<name>.py``, loaded from its file."""
+        """``<root>/<kind>/<name>.py``, loaded from its file (and entered
+        in ``sys.modules``, where a dataclass of the module looks for
+        its module)."""
         path = self.root / kind / f"{name}.py"
         spec = importlib.util.spec_from_file_location(
             f"benchmark.{kind}.{name}", path)
         if spec is None or not path.is_file():
             raise FileNotFoundError(f"no {kind} module {path}")
         mod = importlib.util.module_from_spec(spec)
+        sys.modules[spec.name] = mod
         spec.loader.exec_module(mod)
         return mod
 
@@ -125,7 +144,7 @@ class Check:
 class Run:
     """What a metric reader is given."""
     cell: dict
-    mix: traffic.Mix
+    mix: object                     # what checkers/<checker>.mix returns
     pool: list
     checks: list
     setup_s: float
@@ -229,11 +248,7 @@ def warm_up(pool: list) -> list[int]:
 def answers(chk, pool: list, needed, which: str) -> dict:
     """{pool index: answer} of the plain reference (or, ``which=
     "control"``, of the control) for every pool index in ``needed``."""
-    check = reference.CHECKS[which]
-    return {j: chk.reference_answer(
-                {key: check(h) for key, h in
-                 chk.reference_keys(pool[j].history).items()})
-            for j in sorted(needed)}
+    return {j: chk.reference(pool[j].history, which) for j in sorted(needed)}
 
 
 def run(bench: Bench, cell_name: str, seed: int, seconds: float,
@@ -258,7 +273,7 @@ def run(bench: Bench, cell_name: str, seed: int, seconds: float,
         f"seed {seed}")
     pool = []
     for j in range(mix.pool):
-        pool.append(traffic.make_history(mix, seed, j))
+        pool.append(chk.history(mix, seed, j))
         log(f"history {j}: {pool[j].ops} ops, planted {pool[j].plants}")
 
     summary = peak = None

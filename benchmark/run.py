@@ -17,9 +17,10 @@ Those numbers are also the last lines on standard error.
 A machine where JAX finds no TPU, or fewer chips than the cell asks for,
 gets exit code 2 and no result line.
 
-``--control N`` puts the control (the reference with real-time order
-dropped) in the program's place for N checks of the cell's histories and
-compares it like a run; its ``correct`` must come out false.
+``--control N`` puts the control (the checker module's: for the
+registers, the reference with real-time order dropped) in the program's
+place for N checks of the cell's histories and compares it like a run;
+its ``correct`` must come out false.
 """
 import argparse
 import json

@@ -1,9 +1,12 @@
-"""The one traffic generator: register histories from a mix file and a seed.
+"""The register generator: register histories from a mix file and a seed.
 
-A traffic mix is a JSON file under ``benchmark/traffic/``; this module is
-the only code that reads its generation parameters. Every history a run
-checks is made here from ``--seed``, so the same seed gives the same
-histories, op for op.
+The register configurations' checker modules (``checkers/linearizable.py``
+and ``checkers/independent.py``) make their pools here: a traffic mix is
+a JSON file under ``benchmark/traffic/``, and for those configurations
+this module is the only code that reads its generation parameters. A
+configuration of another kind brings its own generator in its checker
+module and may share ``Planted``. Every history a run checks is made
+from ``--seed``, so the same seed gives the same histories, op for op.
 
 The process model is Jepsen's ``linearizable_register`` workload
 (``jepsen/src/jepsen/tests/linearizable_register.clj``) against a correct
