@@ -231,26 +231,45 @@ def check_cycles(graph: Graph, accelerator: str = "auto") -> dict:
     ``cpu`` keeps the trim + global-Tarjan pipeline unchanged — it is the
     auditable oracle twin the differential tests pin the fast path to.
     Histories without a usable φ (no invocations recorded, or per-process
-    sequentiality violated) fall back to that pipeline too."""
+    sequentiality violated) fall back to that pipeline too.
+
+    The path's host work is named in three checker phases
+    (doc/observability.md "Checker phase spans"): ``dispatch.elle_cluster``
+    (back edges, clusters, their remap, the host screen), one
+    ``dispatch.elle_screen`` per device screen dispatch (ops.scc) and
+    ``settle.elle_classify`` (the typed searches on the live clusters)."""
     if accelerator == "cpu":
         return _check_cycles_global(graph, accelerator)
 
-    codes, src, dst, order = _edge_columns(graph)
-    if order is None:
+    from jepsen_tpu import trace
+    with trace.phase("dispatch.elle_cluster", clusters=0, device_screened=0,
+                     host_screened=0, oversized=0) as span:
+        codes, src, dst, order = _edge_columns(graph)
+        clusters = _back_edge_clusters(codes, src, dst, order)
+        if clusters:
+            remapped, live, batches, counts = _screen_plan(
+                codes, src, dst, order, clusters, accelerator)
+            span.set(clusters=len(clusters), **counts)
+    if clusters is None:
         return _check_cycles_global(graph, accelerator)
+    if not clusters:
+        return {}  # all dependency edges increase φ: acyclic in every stage
+    return _check_cycles_clusters(remapped, live, batches)
 
-    dep_mask = codes <= 2
+
+def _back_edge_clusters(codes, src, dst, order):
+    """The φ-clusters of the graph's back edges ([] where every
+    dependency edge increases φ), or None where φ is unusable."""
+    if order is None:
+        return None
     o_s, o_d = order[src], order[dst]
     if ((o_s < 0) | (o_d < 0)).any():
         # a node never matched to a history event: φ is unusable
-        return _check_cycles_global(graph, accelerator)
-    back = dep_mask & (o_d <= o_s)
+        return None
+    back = (codes <= 2) & (o_d <= o_s)
     if not back.any():
-        return {}  # all dependency edges increase φ: acyclic in every stage
-
-    clusters = _phi_clusters(order[src[back]], order[dst[back]])
-    return _check_cycles_clusters(codes, src, dst, order, clusters,
-                                  accelerator)
+        return []
+    return _phi_clusters(order[src[back]], order[dst[back]])
 
 
 _TYPE_CODE = {WW: 0, WR: 1, RW: 2, REALTIME: 3, PROCESS: 4}
@@ -301,16 +320,19 @@ def _phi_clusters(back_src_phi: np.ndarray, back_dst_phi: np.ndarray):
     return out
 
 
-def _check_cycles_clusters(codes, src, dst, order, clusters,
-                           accelerator: str) -> dict:
-    """Classifies anomalies cluster by cluster. Every edge (any type) with
-    both endpoint φs inside a cluster's interval joins that cluster's
-    subgraph; the device screen proves most clusters acyclic in a few
-    batched dispatches and the exact typed searches run only on the rest.
+def _screen_plan(codes, src, dst, order, clusters, accelerator: str):
+    """Every edge (any type) with both endpoint φs inside a cluster's
+    interval joins that cluster's subgraph. Clusters are remapped to
+    dense local ids ONCE, then grouped into size buckets for the screen
+    — so a thousand 4-node clusters never pay a single big cluster's
+    [V, V] matrix footprint. Buckets too small for a device dispatch are
+    screened here by host Tarjan; the rest are packed for the device.
 
-    Clusters are remapped to dense local ids ONCE, then grouped into
-    size buckets for the screen — so a thousand 4-node clusters never
-    pay a single big cluster's [V, V] matrix footprint."""
+    Returns (remapped: (n_local, local_edges, to_global) per cluster or
+    None without edges; live: the clusters the host screen found cyclic
+    and the oversized ones; batches: (members, V bucket, (cid, src,
+    dst)) per device dispatch; the counts of the ``dispatch.elle_cluster``
+    span)."""
     from jepsen_tpu.ops import scc as scc_mod
     from jepsen_tpu.ops.jitlin import _bucket
 
@@ -349,6 +371,8 @@ def _check_cycles_clusters(codes, src, dst, order, clusters,
         buckets.setdefault(_bucket(rm[0], floor=8), []).append(c)
 
     live: list = []
+    batches: list = []
+    host_screened = 0
     for vb, members in sorted(buckets.items()):
         use_device = accelerator == "tpu" or (
             accelerator == "auto"
@@ -362,23 +386,39 @@ def _check_cycles_clusters(codes, src, dst, order, clusters,
                     packed_cid.append(b)
                     packed_src.append(s)
                     packed_dst.append(d)
-            flags = scc_mod.batch_cluster_screen(
-                np.asarray(packed_cid, np.int32),
-                np.asarray(packed_src, np.int32),
-                np.asarray(packed_dst, np.int32), len(members), vb)
-            live += [c for c, f in zip(members, flags.tolist()) if f]
+            batches.append((members, vb,
+                            (np.asarray(packed_cid, np.int32),
+                             np.asarray(packed_src, np.int32),
+                             np.asarray(packed_dst, np.int32))))
         else:
             # host screen: no nontrivial SCC means no cycles
+            host_screened += len(members)
             live += [c for c in members
                      if scc_mod.tarjan_scc(
                          remapped[c][0],
                          [(s, d) for s, d, _ in remapped[c][1]])]
     live += big  # oversized clusters go straight to the exact pass
+    counts = {"device_screened": sum(len(b[0]) for b in batches),
+              "host_screened": host_screened, "oversized": len(big)}
+    return remapped, live, batches, counts
+
+
+def _check_cycles_clusters(remapped, live, batches) -> dict:
+    """Classifies anomalies cluster by cluster: the device screen proves
+    most packed clusters acyclic in a few batched dispatches and the
+    exact typed searches run only on the clusters found live."""
+    from jepsen_tpu import trace
+    from jepsen_tpu.ops import scc as scc_mod
+
+    for members, vb, (cid, src, dst) in batches:
+        flags = scc_mod.batch_cluster_screen(cid, src, dst, len(members), vb)
+        live += [c for c, f in zip(members, flags.tolist()) if f]
 
     anomalies: dict[str, list] = {}
-    for c in sorted(live):
-        n_local, local_edges, to_global = remapped[c]
-        _classify_stages(n_local, local_edges, to_global, anomalies)
+    with trace.phase("settle.elle_classify", clusters=len(live)):
+        for c in sorted(live):
+            n_local, local_edges, to_global = remapped[c]
+            _classify_stages(n_local, local_edges, to_global, anomalies)
     return anomalies
 
 

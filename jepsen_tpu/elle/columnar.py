@@ -82,10 +82,12 @@ def check_columnar(history: list, consistency_models, accelerator: str,
     cyc = elle.check_cycles(graph, accelerator=accelerator)
     LAST_PHASE_SECONDS.update(build=round(t1 - t0, 3),
                               cycles=round(_time.perf_counter() - t1, 3))
-    merged_extras = {k: v for k, v in extras.items()
-                     if k != "unobserved-writer"}
-    result = elle.result_map(cyc, txns, merged_extras,
-                             consistency_models=consistency_models)
+    from jepsen_tpu import trace
+    with trace.phase("settle.elle_classify"):
+        merged_extras = {k: v for k, v in extras.items()
+                         if k != "unobserved-writer"}
+        result = elle.result_map(cyc, txns, merged_extras,
+                                 consistency_models=consistency_models)
     result["txn-count"] = graph.n
     result["edge-count"] = graph.edge_count()
     result["read-scan-keys"] = {"columnar": n_keys, "python": 0}
@@ -95,7 +97,17 @@ def check_columnar(history: list, consistency_models, accelerator: str,
 
 def _build(history: list):
     """Dependency-graph build: C parser when available, numpy otherwise.
-    Returns (graph, txns, extras, n_keys) or None (regime miss)."""
+    Returns (graph, txns, extras, n_keys) or None (regime miss). The
+    ``encode.elle_build`` checker phase, timing edges included."""
+    from jepsen_tpu import trace
+    with trace.phase("encode.elle_build", events=len(history)) as span:
+        parts = _build_parts(history)
+        if parts is not None:
+            span.set(txns=parts[0].n, edges=parts[0].edge_count())
+        return parts
+
+
+def _build_parts(history: list):
     m = _cmod()
     if m is not None:
         try:

@@ -214,10 +214,12 @@ def _screen_kernel(n_clusters: int, n_local: int, n_edges: int):
     if fn is not None:
         return fn
 
-    n_steps = max(1, int(np.ceil(np.log2(max(2, n_local)))))
+    n_steps = screen_steps(n_local)
 
+    # its own name, so the profiler tells its XLA program
+    # (``jit_cluster_screen``) from the trim's and the frontier scan's
     @jax.jit
-    def run(cid, src_l, dst_l, valid):
+    def cluster_screen(cid, src_l, dst_l, valid):
         adj = jnp.zeros((n_clusters, n_local, n_local), jnp.bfloat16)
         adj = adj.at[cid, src_l, dst_l].max(
             jnp.where(valid, jnp.bfloat16(1), jnp.bfloat16(0)))
@@ -233,8 +235,14 @@ def _screen_kernel(n_clusters: int, n_local: int, n_edges: int):
         diag = jnp.diagonal(closure, axis1=1, axis2=2)
         return jnp.any(diag > 0, axis=1)
 
-    _SCREEN_CACHE[key] = run
-    return run
+    _SCREEN_CACHE[key] = cluster_screen
+    return cluster_screen
+
+
+def screen_steps(n_local: int) -> int:
+    """Squaring steps of the closure over ``n_local`` nodes:
+    ``ceil(log2(V))``, at least one."""
+    return max(1, int(np.ceil(np.log2(max(2, n_local)))))
 
 
 # ceiling on one screen dispatch's [B, V, V] element count: bf16
@@ -279,6 +287,8 @@ def batch_cluster_screen(cid: np.ndarray, src_l: np.ndarray,
                 b1 - b0, max_local)
         return out
 
+    from jepsen_tpu import trace
+
     bb = _bucket(n_clusters, floor=8)
     eb = _bucket(len(cid), floor=64)
     pad = eb - len(cid)
@@ -289,8 +299,12 @@ def batch_cluster_screen(cid: np.ndarray, src_l: np.ndarray,
     dst_p = np.concatenate([np.asarray(dst_l, np.int32),
                             np.zeros(pad, np.int32)])
     valid = np.concatenate([np.ones(len(cid), bool), np.zeros(pad, bool)])
-    run = _screen_kernel(bb, vb, eb)
-    return np.asarray(run(cid_p, src_p, dst_p, valid))[:n_clusters]
+    screen = _screen_kernel(bb, vb, eb)
+    # one dispatch, readback included, with the bucketed shapes the
+    # device works on
+    with trace.phase("dispatch.elle_screen", b=bb, v=vb, e=eb,
+                     steps=screen_steps(vb)):
+        return np.asarray(screen(cid_p, src_p, dst_p, valid))[:n_clusters]
 
 
 def tarjan_scc(n_nodes: int, edges: list[tuple[int, int]]) -> list[list[int]]:

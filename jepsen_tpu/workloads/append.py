@@ -19,13 +19,20 @@ class AppendChecker(Checker):
         return "elle-list-append"
 
     def check(self, test, history, opts):
-        from jepsen_tpu import history_ir
+        from jepsen_tpu import trace
+        with trace.phase(trace.CHECK_SPAN, ops=len(history)):
+            return self._check(test, history, opts)
+
+    def _check(self, test, history, opts):
+        from jepsen_tpu import history_ir, trace
+        with trace.phase("encode.ir", events=len(history)):
+            ir = history_ir.of(test, history)
         result = list_append.check(
             history,
             accelerator=opts.get("accelerator", self.accelerator),
             consistency_models=opts.get("consistency_models",
                                         self.consistency_models),
-            ir=history_ir.of(test, history))
+            ir=ir)
         # invalid check: leave human-readable per-anomaly explanation
         # files under store/<test>/<ts>/elle/ (the reference passes
         # elle :directory per test, append.clj:17-22)
