@@ -101,13 +101,14 @@ def _build(history: list):
     ``encode.elle_build`` checker phase, timing edges included."""
     from jepsen_tpu import trace
     with trace.phase("encode.elle_build", events=len(history)) as span:
-        parts = _build_parts(history)
+        stats: dict = {}
+        parts = _build_parts(history, stats)
         if parts is not None:
-            span.set(txns=parts[0].n, edges=parts[0].edge_count())
+            span.set(txns=parts[0].n, edges=parts[0].edge_count(), **stats)
         return parts
 
 
-def _build_parts(history: list):
+def _build_parts(history: list, stats: dict):
     m = _cmod()
     if m is not None:
         try:
@@ -115,11 +116,11 @@ def _build_parts(history: list):
         except Exception:  # noqa: BLE001 - never fail the check over C
             out = None
         if out is not None:
-            return _build_from_c(out)
-    return _build_py(history)
+            return _build_from_c(out, stats)
+    return _build_py(history, stats)
 
 
-def _build_from_c(out):
+def _build_from_c(out, stats: dict):
     """Adapts the C parser's 25-tuple into the shared tail's inputs."""
     (n_ok, nk, node_pos_b, node_inv_b, node_proc_b, txns,
      a_txn_b, a_kid_b, a_val_b, a_mi_b,
@@ -149,7 +150,7 @@ def _build_from_c(out):
         spine_of=spine_of,
         scrutiny=set(scrutiny_l), rows_by_kid=None,
         node_pos=b(node_pos_b), node_inv=b(node_inv_b),
-        node_proc=b(node_proc_b))
+        node_proc=b(node_proc_b), stats=stats)
 
 
 class NeedsObjects(Exception):
@@ -376,7 +377,7 @@ def _flatten_mops_fast(txns):
             payloads, raw_key, kid_of)
 
 
-def _build_py(history: list):
+def _build_py(history: list, stats: dict):
     # ---- pass A: event extraction + invocation pairing -----------------
     # Closed form of the pending-dict walk: a completion's invocation is
     # the previous event of the same process iff that event is an invoke
@@ -476,12 +477,12 @@ def _build_py(history: list):
                      r_txn=r_txn, r_kid=r_kid, r_mi=r_mi,
                      payloads=payloads, f_kid=f_kid, f_val=f_val,
                      node_pos=node_pos, node_inv=node_inv,
-                     node_proc=node_proc)
+                     node_proc=node_proc, stats=stats)
 
 
 def _assemble(*, txns, n_ok, raw_key, a_txn, a_kid, a_val, a_mi,
               r_txn, r_kid, r_mi, payloads, f_kid, f_val,
-              node_pos, node_inv, node_proc):
+              node_pos, node_inv, node_proc, stats=None):
     """Array build + spine selection + prefix verification over flattened
     micro-op columns, ending in the shared :func:`_tail`. Factored out of
     ``_build_py`` so the live checker's incremental builder
@@ -582,16 +583,20 @@ def _assemble(*, txns, n_ok, raw_key, a_txn, a_kid, a_val, a_mi,
         S_concat=S_concat, s_kid=s_kid, soff_of_kid=soff_of_kid,
         slen_of_kid=slen_of_kid, spine_of=spine_list_of_kid.__getitem__,
         scrutiny=scrutiny, rows_by_kid=rows_by_kid,
-        node_pos=node_pos, node_inv=node_inv, node_proc=node_proc)
+        node_pos=node_pos, node_inv=node_inv, node_proc=node_proc,
+        stats=stats)
 
 
 def _tail(*, txns, n, n_ok, nk, raw_key,
           A_txn, A_kid, A_val, A_mi, F_comp,
           R_txn, R_kid, R_mi, lens, last_arr, R_isok, payloads,
           S_concat, s_kid, soff_of_kid, slen_of_kid, spine_of,
-          scrutiny, rows_by_kid, node_pos, node_inv, node_proc):
+          scrutiny, rows_by_kid, node_pos, node_inv, node_proc,
+          stats=None):
     """Shared analysis tail over the columnar product (either front):
-    writer maps, anomaly scans, edge derivation, timing edges."""
+    writer maps, anomaly scans, edge derivation, timing edges. A
+    ``stats`` dict gets the G1b walk's counts (``g1b_candidates``,
+    ``g1b_walked``)."""
     extras: dict[str, list] = defaultdict(list)
     n_reads = len(payloads)
 
@@ -619,6 +624,15 @@ def _tail(*, txns, n, n_ok, nk, raw_key,
             {"key": raw_key[int(A_kid[j])], "value": int(A_val[j])})
     W_comp = ac_sorted[first]
     W_txn = A_txn[a_order][first]
+    # each writer's own appends to each key in micro-op order: the
+    # appends that set a writer (a repeated append of a (key, value) is
+    # only a duplicate-appends finding, as in the oracle's writer maps)
+    sets_writer = np.empty(first.size, bool)
+    sets_writer[a_order] = first
+    mop_comp = (A_txn << 32) | (A_kid << 12) | A_mi
+    mop_order = np.argsort(mop_comp, kind="stable")
+    own = mop_order[sets_writer[mop_order]]
+    own_tk = (A_txn[own] << 32) | A_kid[own]
 
     def writer_lookup(comps):
         if W_comp.size == 0:
@@ -647,10 +661,7 @@ def _tail(*, txns, n, n_ok, nk, raw_key,
     w_of_spine = writer_lookup(comp_spine)
     f_hit_spine = failed_lookup(comp_spine)
     # multi-append writers per (txn, key): the only possible G1b sources
-    TK = (A_txn << 32) | A_kid
-    tks = np.sort(TK)
-    multi_tk = np.unique(tks[1:][tks[1:] == tks[:-1]]) if tks.size else \
-        np.asarray([], np.int64)
+    multi_tk = np.unique(own_tk[1:][own_tk[1:] == own_tk[:-1]])
 
     def spine_elem_hits(mask):
         """(kid, local position, global elem) for flagged spine elems."""
@@ -667,11 +678,9 @@ def _tail(*, txns, n, n_ok, nk, raw_key,
         if not _maps:
             writer_txn: dict = {}
             appends_ptk: dict = defaultdict(list)
-            srt = np.argsort((A_txn << 32) | (A_kid << 12) | A_mi,
-                             kind="stable")
-            for j in srt.tolist():
-                appends_ptk[(int(A_txn[j]), int(A_kid[j]))].append(
-                    int(A_val[j]))
+            for t, k, v in zip(A_txn[own].tolist(), A_kid[own].tolist(),
+                               A_val[own].tolist()):
+                appends_ptk[(t, k)].append(v)
             for comp, w in zip(W_comp.tolist(), W_txn.tolist()):
                 writer_txn[(comp >> 32, comp & 0xFFFFFFFF)] = w
             failed = {(c >> 32, c & 0xFFFFFFFF) for c in F_comp.tolist()}
@@ -746,23 +755,20 @@ def _tail(*, txns, n, n_ok, nk, raw_key,
         for j in clean_rows_of(k, q):
             extras["unobserved-writer"].append(
                 {"key": raw_key[k], "value": int(S_concat[e])})
-    if multi_tk.size and S_concat.size:
-        elem_tk = (w_of_spine << 32) | s_kid
-        pos = np.clip(np.searchsorted(multi_tk, elem_tk), 0,
-                      multi_tk.size - 1)
-        m_hit = (multi_tk[pos] == elem_tk) & (w_of_spine >= 0)
-        g1b_rows: set = set()
-        for k, q, _ in spine_elem_hits(m_hit):
-            g1b_rows.update(clean_rows_of(k, q))
-        for j in sorted(g1b_rows):
-            g1b_row(j)
+    walk, n_candidates = _g1b_reads(
+        S_concat=S_concat, s_kid=s_kid, soff_of_kid=soff_of_kid,
+        slen_of_kid=slen_of_kid, w_of_spine=w_of_spine, n_ok=n_ok,
+        own_tk=own_tk, own_val=A_val[own], multi_tk=multi_tk,
+        R_kid=R_kid, lens=lens, R_isok=R_isok, scrutiny=scrutiny)
+    for j in walk.tolist():
+        g1b_row(j)
+    if stats is not None:
+        stats.update(g1b_candidates=n_candidates, g1b_walked=int(walk.size))
 
     # ---- internal: own reads must reflect own earlier appends ----------
     if A_mi.size and n_reads:
-        a3_order = np.argsort((A_txn << 32) | (A_kid << 12) | A_mi,
-                              kind="stable")
-        a3 = ((A_txn << 32) | (A_kid << 12) | A_mi)[a3_order]
-        a3_val = A_val[a3_order]
+        a3 = mop_comp[mop_order]
+        a3_val = A_val[mop_order]
         base = (R_txn << 32) | (R_kid << 12)
         lo = np.searchsorted(a3, base)
         hi = np.searchsorted(a3, base | R_mi)
@@ -870,3 +876,64 @@ def _tail(*, txns, n, n_ok, nk, raw_key,
     graph = Graph(n, edges=[], time_order=order if sequential_ok else None,
                   cols=cols)
     return graph, txns, extras, nk
+
+
+def _g1b_reads(*, S_concat, s_kid, soff_of_kid, slen_of_kid, w_of_spine,
+               n_ok, own_tk, own_val, multi_tk, R_kid, lens, R_isok,
+               scrutiny):
+    """The clean reads the per-writer G1b check has to walk, in row
+    order, and how many clean reads reach a multi-append writer's
+    element at all (what a walk of every such read would take).
+
+    A clean read of length L is a prefix of its key's spine, which
+    repeats no value, so it observes exactly writer w's spine elements
+    at positions below L: q_0 < q_1 < ..., at most m of them, each a
+    value of A_w, w's m own appends to the key (``own_tk``/``own_val``).
+    The read breaks w iff L > q_0, unless w's elements are A_w in order
+    and L > q_{m-1}. So a read is walked iff its last element lies in
+    [q_0, q_{m-1}) of a writer whose elements are A_w in order, or at or
+    past q_0 of any other committed multi-append writer: a superset of
+    the reads that find G1b or incompatible-order (own reads are still
+    walked). In a valid history that is only the reads that end inside
+    their own txn's append group."""
+    clean = R_isok.copy()
+    if scrutiny:
+        clean[np.fromiter(scrutiny, np.int64, len(scrutiny))] = False
+    rows = np.nonzero(clean & (lens > 0))[0]
+    if not (multi_tk.size and rows.size):
+        return np.zeros(0, np.int64), 0
+    # a clean read's last element as a global spine position
+    last = soff_of_kid[R_kid[rows]] + lens[rows] - 1
+    elem_tk = (w_of_spine << 32) | s_kid
+    pos = np.clip(np.searchsorted(multi_tk, elem_tk), 0, multi_tk.size - 1)
+    hits = np.nonzero((multi_tk[pos] == elem_tk) & (w_of_spine >= 0))[0]
+    first_hit = np.full(soff_of_kid.size, S_concat.size, np.int64)
+    np.minimum.at(first_hit, s_kid[hits], hits)
+    n_candidates = int(np.count_nonzero(last >= first_hit[R_kid[rows]]))
+
+    # committed writers' elements grouped per (writer, key), in spine
+    # order within a group
+    g = hits[w_of_spine[hits] < n_ok]
+    if not g.size:
+        return np.zeros(0, np.int64), n_candidates
+    g = g[np.argsort(elem_tk[g], kind="stable")]
+    gtk = elem_tk[g]
+    gs = np.nonzero(np.r_[True, gtk[1:] != gtk[:-1]])[0]
+    count = np.diff(np.r_[gs, g.size])
+    gid = np.repeat(np.arange(gs.size), count)
+    lo = np.searchsorted(own_tk, gtk[gs])
+    m = np.searchsorted(own_tk, gtk[gs], side="right") - lo
+    # a key whose spine repeats a value can hold more than m of w's
+    # elements; its reads are all under scrutiny, but stay in bounds
+    rank = np.arange(g.size) - gs[gid]
+    inb = rank < m[gid]
+    same = np.zeros(g.size, bool)
+    same[inb] = S_concat[g[inb]] == own_val[lo[gid[inb]] + rank[inb]]
+    whole = (count == m) & \
+        (np.bincount(gid, weights=same, minlength=gs.size) == m)
+    kid = s_kid[g[gs]]
+    ends = np.where(whole, g[gs + np.where(whole, m - 1, 0)],
+                    soff_of_kid[kid] + slen_of_kid[kid])
+    depth = np.cumsum(np.bincount(g[gs], minlength=S_concat.size + 1)
+                      - np.bincount(ends, minlength=S_concat.size + 1))
+    return rows[depth[last] > 0], n_candidates

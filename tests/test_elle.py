@@ -1,6 +1,11 @@
 """Elle-equivalent txn checker tests: classic Adya anomaly constructions +
 serializable histories + device/CPU trim agreement."""
+import copy
+import functools
+import json
 import random
+from collections import Counter, defaultdict
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -1505,3 +1510,174 @@ def test_stored_columns_parity_fuzz():
         assert r["anomaly-types"] == r0["anomaly-types"], trial
         assert r["edge-count"] == r0["edge-count"], trial
     assert compared >= 5 and deferred >= 5, (compared, deferred)
+
+
+# ---------------------------------------------------------------------------
+# the columnar G1b walk: only reads a multi-append writer could break
+# ---------------------------------------------------------------------------
+
+G1B_TYPES = ("G1b", "incompatible-order")
+
+
+def _txn(t, p, value):
+    return [{"type": "invoke", "process": p, "f": "txn",
+             "value": [[f, k, None if f == "r" else v] for f, k, v in value]},
+            {"type": t, "process": p, "f": "txn", "value": value}]
+
+
+def _g1b_hand_histories() -> dict:
+    """name -> (history, G1b-family types it must show)."""
+    def h(*txns):
+        return [op for t in txns for op in _txn(*t)]
+
+    return {
+        # T0 appends 1, 2; T2 reads [1]: it ends inside T0's group
+        "read-inside-group": (h(
+            ("ok", 0, [["append", 0, 1], ["append", 0, 2]]),
+            ("ok", 1, [["r", 0, [1, 2]]]),
+            ("ok", 2, [["r", 0, [1]]])), {"G1b"}),
+        # the spine holds T0's appends as 2, 1
+        "spine-out-of-order": (h(
+            ("ok", 0, [["append", 0, 1], ["append", 0, 2]]),
+            ("ok", 1, [["r", 0, [2, 1]]]),
+            ("ok", 2, [["r", 0, [2]]])), {"incompatible-order"}),
+        # T0 appends 1, 2, 3; no read ever shows 3
+        "fewer-spine-elements-than-appends": (h(
+            ("ok", 0, [["append", 0, 1], ["append", 0, 2],
+                       ["append", 0, 3]]),
+            ("ok", 1, [["r", 0, [1, 2]]]),
+            ("ok", 2, [["r", 0, [1]]])), {"G1b"}),
+        # T0 appends 5, 6, 5: the second 5 is only a duplicate append,
+        # so [5, 6] shows T0 whole and [5] shows it in part
+        "same-value-twice": (h(
+            ("ok", 0, [["append", 0, 5], ["append", 0, 6],
+                       ["append", 0, 5]]),
+            ("ok", 1, [["r", 0, [5, 6]]]),
+            ("ok", 2, [["r", 0, [5]]])), {"G1b"}),
+        # T0 reads its own first append between its two appends
+        "own-read-inside-group": (h(
+            ("ok", 0, [["append", 0, 1], ["r", 0, [1]], ["append", 0, 2]]),
+            ("ok", 1, [["r", 0, [1, 2]]])), set()),
+        # an indeterminate writer seen in part is no G1b
+        "info-writer": (h(
+            ("info", 0, [["append", 0, 1], ["append", 0, 2]]),
+            ("ok", 1, [["r", 0, [1, 2]]]),
+            ("ok", 2, [["r", 0, [1]]])), set()),
+    }
+
+
+@functools.lru_cache(maxsize=1)
+def _g1b_fuzz_histories() -> tuple:
+    """test_columnar_builder_parity_fuzz's 60 histories (seed 23)."""
+    rng = random.Random(23)
+    return tuple(_messy_history(rng) for _ in range(60))
+
+
+def _checked(h, accelerator, monkeypatch):
+    """(result, every G1b-family entry as a multiset, the stats of
+    each ``encode.elle_build`` span) of one ``list_append.check``. The
+    entries are read where the check hands them to ``result_map``,
+    which keeps only ten of each type."""
+    from jepsen_tpu import elle, trace
+    from jepsen_tpu.trace.flight import FlightRecorder
+
+    handed = []
+    result_map = elle.result_map
+
+    def keep(anomalies, txns, extras=None, **kw):
+        handed.append(extras or {})
+        return result_map(anomalies, txns, extras, **kw)
+
+    tracer = trace.RunTracer(flight=FlightRecorder(256))
+    with monkeypatch.context() as mp, trace.use(tracer):
+        mp.setattr(elle, "result_map", keep)
+        r = list_append.check(copy.deepcopy(h), accelerator=accelerator)
+    [extras] = handed
+    entries = {t: Counter(json.dumps(e, sort_keys=True)
+                          for e in extras.get(t, ())) for t in G1B_TYPES}
+    builds = [ev["args"] for ev in tracer.flight.snapshot()
+              if isinstance(ev, dict) and ev.get("name") == "encode.elle_build"]
+    return r, entries, builds
+
+
+@pytest.mark.parametrize(
+    "case", [f"fuzz-{i}" for i in range(60)] + sorted(_g1b_hand_histories()))
+def test_g1b_walk_matches_oracle(case, monkeypatch):
+    """Every G1b and incompatible-order entry the columnar build finds
+    (both fronts) is the CPU oracle's, as a multiset, though it walks
+    only the reads that end where a committed multi-append writer can
+    be seen in part or out of order."""
+    from jepsen_tpu.elle import columnar
+
+    if case.startswith("fuzz-"):
+        h, shows = _g1b_fuzz_histories()[int(case[5:])], None
+    else:
+        h, shows = _g1b_hand_histories()[case]
+    _, want, _ = _checked(h, "cpu", monkeypatch)
+    if shows is not None:
+        assert {t for t in G1B_TYPES if want[t]} == shows
+    for front in ("c", "py"):
+        with monkeypatch.context() as mp:
+            if front == "py":
+                mp.setattr(columnar, "_cmod", lambda: None)
+            r, got, [build] = _checked(h, "auto", monkeypatch)
+        assert r.get("builder") == "columnar", front
+        assert got == want, (front, got, want)
+        assert 0 <= build["g1b_walked"] <= build["g1b_candidates"], build
+
+
+def _strict_append_history(n_txns: int, seed: int, workers: int = 10):
+    """Elle's list-append txns (``list_append.gen``: 3 rotating keys,
+    1-4 micro-ops) by ``workers`` workers, each txn applied whole at a
+    point inside its interval: strict serializable, every read the key's
+    whole list."""
+    rng = random.Random(seed)
+    one_txn = list_append.gen()
+    ctx = SimpleNamespace(rng=rng)
+    lists: dict = defaultdict(list)
+    h: list = []
+    flight: dict = {}   # process -> (micro-ops, applied value or None)
+    invoked = 0
+
+    def invoke(p):
+        nonlocal invoked
+        invoked += 1
+        mops = one_txn(None, ctx)["value"]
+        h.append({"type": "invoke", "process": p, "f": "txn",
+                  "value": mops})
+        flight[p] = (mops, None)
+
+    for p in range(workers):
+        invoke(p)
+    while flight:
+        p = rng.choice(sorted(flight))
+        mops, applied = flight.pop(p)
+        if applied is None:
+            applied = []
+            for f, k, v in mops:
+                if f == "append":
+                    lists[k].append(v)
+                    applied.append([f, k, v])
+                else:
+                    applied.append([f, k, list(lists[k])])
+            flight[p] = (mops, applied)
+            continue
+        h.append({"type": "ok", "process": p, "f": "txn",
+                  "value": applied})
+        if invoked < n_txns:
+            invoke(p)
+    return h
+
+
+def test_g1b_walk_is_a_sliver_of_a_valid_history(monkeypatch):
+    """On a valid history nearly every read reaches some multi-append
+    writer's elements, and only the own reads that end inside their
+    txn's append group are walked: a few percent at most."""
+    h = _strict_append_history(3000, seed=11)
+    r, entries, [build] = _checked(h, "auto", monkeypatch)
+    assert r["valid?"] is True and r.get("builder") == "columnar"
+    assert not any(entries.values())
+    n_reads = sum(m[0] == "r" for op in h if op["type"] == "ok"
+                  for m in op["value"])
+    assert build["g1b_candidates"] >= n_reads // 2, (build, n_reads)
+    assert build["g1b_walked"] <= 0.03 * build["g1b_candidates"], build
