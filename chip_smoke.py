@@ -338,9 +338,40 @@ def phase_set_full(n_els: int) -> dict:
             "cold_s": cold, "warm_s": warm, "rung": "setscan"}
 
 
+def _elle_history(n_txns: int, n_keys: int = 100, crossed_pairs: int = 0):
+    """Serializable list-append history; ``crossed_pairs`` appends pairs
+    of mutually-observing txns (wr edges both ways → G1c 2-cycles), which
+    defeats the acyclicity screen and forces the trim + cycle search."""
+    history = []
+    t = 0
+
+    def txn(proc, mops_inv, mops_ok):
+        nonlocal t
+        history.append({"type": "invoke", "process": proc,
+                        "value": mops_inv, "time": t})
+        history.append({"type": "ok", "process": proc,
+                        "value": mops_ok, "time": t + 1})
+        t += 2
+
+    for i in range(n_txns):
+        k = i % n_keys
+        seen = list(range(k, i + 1, n_keys))  # every append to k so far
+        txn(i % 10, [["append", k, i], ["r", k, None]],
+            [["append", k, i], ["r", k, seen]])
+    for p in range(crossed_pairs):
+        ka, kb = 10_000 + 2 * p, 10_001 + 2 * p
+        va, vb = 2_000_000 + 2 * p, 2_000_001 + 2 * p
+        # A observes B's append before B commits; B observes A's: a wr
+        # cycle between the two on fresh keys
+        txn(10, [["append", ka, va], ["r", kb, None]],
+            [["append", ka, va], ["r", kb, [vb]]])
+        txn(11, [["append", kb, vb], ["r", ka, None]],
+            [["append", kb, vb], ["r", ka, [va]]])
+    return history
+
+
 def phase_elle(n_txns: int, crossed_pairs: int) -> dict:
     """Elle list-append, device vs the CPU path's anomaly types."""
-    from bench import _elle_history
     from jepsen_tpu.elle import list_append
 
     history = _elle_history(n_txns, crossed_pairs=crossed_pairs)

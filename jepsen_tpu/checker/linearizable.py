@@ -296,7 +296,7 @@ class LinearizableChecker(Checker):
         if phases:
             # the matrix rung may have run on a watchdog thread; make
             # its phase split visible to this thread's readers
-            # (_record_metrics, bench)
+            # (_record_metrics)
             from jepsen_tpu.ops.jitlin import publish_phase_seconds
             publish_phase_seconds(phases)
         return res

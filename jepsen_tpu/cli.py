@@ -383,8 +383,8 @@ def single_test_cmd(
                                  "(env twin JEPSEN_TPU_FUZZ_SEED)")
         p_hunt.add_argument("--blind", action="store_true",
                             help="disable coverage guidance (the "
-                                 "random-baseline bench.py compares "
-                                 "against)")
+                                 "random baseline guided search is "
+                                 "measured against)")
         p_hunt.add_argument("--no-stop-on-first", action="store_true",
                             help="spend the whole trial budget even "
                                  "after an anomaly lands")

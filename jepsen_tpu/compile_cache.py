@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, placed from outside.
 
-Entry points (the CLI, ``bench.py``, ``chip_smoke.py``, the live and
-fleet daemons) call :func:`enable` before their first compile. Where
+Entry points (the CLI, ``chip_smoke.py``, the live and fleet daemons)
+call :func:`enable` before their first compile. Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing
 here overrides it. Otherwise the cache lives at the fixed
 ``<checkout>/.jax_cache``: the directory is part of what a later

@@ -46,12 +46,6 @@ _MAX_MOPS = 1 << 12
 _MAX_VAL = 1 << 32
 
 
-# phase timings of the most recent check_columnar call (seconds); a
-# diagnosis surface for benchmark trial spread — build is host-side
-# C/numpy, cycles is the (possibly device) screen + search
-LAST_PHASE_SECONDS: dict = {}
-
-
 def _cmod():
     """The native C parser module, or None (pure-Python fallback)."""
     from jepsen_tpu.native import columnar_c
@@ -65,10 +59,7 @@ def check_columnar(history: list, consistency_models, accelerator: str,
     ``parts`` short-circuits the build phase with a precomputed
     ``_build`` product — the history-IR view
     (jepsen_tpu.history_ir.views.elle_build) passes it so a run that
-    already encoded pays ~zero build here (``phase_build_s`` then
-    measures just the handoff)."""
-    import time as _time
-    t0 = _time.perf_counter()
+    already encoded pays ~zero build here."""
     if parts is None:
         try:
             parts = _build(history)
@@ -77,11 +68,8 @@ def check_columnar(history: list, consistency_models, accelerator: str,
     if parts is None:
         return None
     graph, txns, extras, n_keys = parts
-    t1 = _time.perf_counter()
 
     cyc = elle.check_cycles(graph, accelerator=accelerator)
-    LAST_PHASE_SECONDS.update(build=round(t1 - t0, 3),
-                              cycles=round(_time.perf_counter() - t1, 3))
     from jepsen_tpu import trace
     with trace.phase("settle.elle_classify"):
         merged_extras = {k: v for k, v in extras.items()
@@ -264,8 +252,6 @@ def check_columns(cols: dict, consistency_models=("strict-serializable",),
     parse. Raises :class:`NeedsObjects` when a finding needs to cite
     txn values (anomalous histories); the clean path completes
     entirely from the arrays."""
-    import time as _time
-    t0 = _time.perf_counter()
     a = {k: np.asarray(cols[k]) for k in ELLE_COLUMN_KEYS}
     n_ok, nk = int(a["n_ok"]), int(a["nk"])
     payloads = _PayloadView(a["payload_concat"], a["payload_off"])
@@ -291,10 +277,7 @@ def check_columns(cols: dict, consistency_models=("strict-serializable",),
         scrutiny=set(a["scrutiny"].tolist()), rows_by_kid=None,
         node_pos=a["node_pos"], node_inv=a["node_inv"],
         node_proc=a["node_proc"])
-    t1 = _time.perf_counter()
     cyc = elle.check_cycles(graph, accelerator=accelerator)
-    LAST_PHASE_SECONDS.update(build=round(t1 - t0, 3),
-                              cycles=round(_time.perf_counter() - t1, 3))
     merged_extras = {k: v for k, v in extras.items()
                      if k != "unobserved-writer"}
     result = elle.result_map(cyc, txns, merged_extras,

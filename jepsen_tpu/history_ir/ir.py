@@ -13,8 +13,7 @@ the Arrow-style one-layout-many-consumers stance). It *promotes*
   its encoding (register event stream, Elle builder columns, set
   membership matrix, per-key sub-histories — see
   :mod:`jepsen_tpu.history_ir.views`) from the IR exactly once per run;
-  a second checker over the same history pays ~nothing
-  (``ir_encode_amortization`` in bench.py pins this);
+  a second checker over the same history pays ~nothing;
 * **device placement** (:meth:`DeviceHistory.device_columns`): the
   canonical columns staged onto the accelerator — single-device or
   padded + sharded over a :func:`jepsen_tpu.parallel.auto_mesh` mesh

@@ -17,8 +17,8 @@ re-checks a pure array pipeline:
 
 Because the view products are derived from the SAME IR the run's
 checkers used (``history_ir.of`` memoizes per run), ``analyze``
-re-checks and bench's stored-columns lane ride the same encode — the
-sidecar is a cache of the IR, not a third encoder.
+re-checks ride the same encode — the sidecar is a cache of the IR, not
+a third encoder.
 
 Sidecar schema (doc/performance.md "History IR"):
 

@@ -3,7 +3,7 @@
 Every checker backend's encoding is a *view* derived from one
 :class:`~jepsen_tpu.history_ir.ir.DeviceHistory`, memoized on the IR
 instance (``dh.view``), so a multi-checker run (Compose, the analyze
-re-check, the bench lanes) pays each encode exactly once:
+re-check) pays each encode exactly once:
 
 * :func:`register_stream` / :func:`multi_register_stream` — the
   linearizability :class:`~jepsen_tpu.checker.linear_encode.EventStream`

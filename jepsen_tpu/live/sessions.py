@@ -16,8 +16,8 @@ without re-reading or re-encoding the prefix it has already seen:
   inherits the post-hoc checker's demotion/watchdog/breaker policy.
 * :class:`ElleSession` — list-append transactional anomalies. The
   PyObject-heavy build phase (event pairing + micro-op flattening +
-  key interning — the ``phase_build_s`` that dominates a post-hoc Elle
-  check ~7:1, BENCH_r04) is the history IR's incremental Elle builder
+  key interning, the larger share of a post-hoc Elle check) is the
+  history IR's incremental Elle builder
   (:class:`jepsen_tpu.history_ir.builder.LiveElleColumns`), run once
   per op as it arrives; each verdict then only pays the vectorized
   assemble + cycle check (``elle.columnar._assemble`` — the exact

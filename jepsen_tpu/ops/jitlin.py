@@ -43,8 +43,8 @@ logger = logging.getLogger("jepsen.jitlin")
 
 # Host/device phase split of the calling thread's most recent
 # matrix_check_batch call (prepass / grids / dispatch / fetch seconds) —
-# bench.py folds these into the matrix-kernel attribution fields the way
-# elle's bench reads columnar.LAST_PHASE_SECONDS. Thread-local:
+# the checker's _record_metrics exports it as the
+# checker_matrix_phase_seconds gauges. Thread-local:
 # concurrent checkers under bounded_pmap must not read each other's
 # split (or trip over a mid-update clear()).
 _PHASE = threading.local()
@@ -67,7 +67,7 @@ def publish_phase_seconds(phases: dict) -> None:
 # Most recent dispatch routing of the calling thread: which kernel
 # representation ran the chunk products ("f32"/"int8"/"packed", or
 # "scan" for the XLA path) and which combine ("fused"/"tree") — the
-# per-variant labels bench.py attaches to its phase/roofline fields.
+# labels of the checker_matrix_variant_total counter.
 _DISPATCH_INFO = threading.local()
 
 
@@ -623,8 +623,7 @@ def _kernel_math(S: int, V: int, step_ids, G: int):
             # neighbors per level ((P1@P0), (P3@P2), ...) computes the same
             # 0/1 product in ceil(log2 C) levels of BATCHED matmuls instead
             # of C sequential [B, MV, MV] products — the old fori_loop chain
-            # was C dependent tiny matmuls of pure launch latency (256 of
-            # them on the single-dispatch bench config).
+            # was C dependent tiny matmuls of pure launch latency.
             def bmm_pairs(hi, lo):
                 out = jnp.einsum("bnij,bnjk->bnik", hi, lo,
                                  preferred_element_type=jnp.bfloat16)
@@ -1146,7 +1145,7 @@ def matrix_segmented_config(S, V, init_state, num_states, max_segment,
     """The knob/shape fingerprint a segmented-matrix checkpoint is
     valid under — ONE constructor shared by the writer
     (matrix_check_segmented) and out-of-band checkpoint authors
-    (bench.py's resume_savings stage, tests), so a fingerprint drift
+    (tests), so a fingerprint drift
     between them is impossible by construction. ``step_ids`` stamps
     the model identity: the prefix hash covers only the encoded
     columns, which are model-independent, so a model swap between
@@ -1423,7 +1422,7 @@ def _publish_phases(phases: dict) -> None:
     """Rounds the measured host/device split and annotates it with the
     dispatch routing labels (variant + combine path) for this thread's
     ``last_phase_seconds`` readers — the per-variant attribution
-    bench.py folds into the matrix metrics."""
+    the checker exports as ``checker_matrix_variant_total``."""
     out = {k: round(v, 4) for k, v in phases.items()}
     out.update(last_dispatch_info())
     _PHASE.value = out

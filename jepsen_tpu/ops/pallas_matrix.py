@@ -525,8 +525,8 @@ _PROBE_SECONDS: list = [0.0]
 def probe_seconds() -> float:
     """Cumulative probe wall this process (compile + oracle replay) —
     the cost ``JEPSEN_TPU_PALLAS_PROBE``'s sidecar cache avoids on
-    later processes. bench.py surfaces it as ``pallas_probe_seconds``
-    so it can't hide inside first-check compile time."""
+    later processes. The ``pallas_probe_seconds_total`` counter exports
+    it so it can't hide inside first-check compile time."""
     return _PROBE_SECONDS[0]
 
 

@@ -16,54 +16,12 @@ Verdict codes: 0 = stable, 1 = lost, 2 = never-read.
 """
 from __future__ import annotations
 
-import threading
-import time
-
 import numpy as np
 
 STABLE, LOST, NEVER_READ = 0, 1, 2
 
 _NEG = np.float32(-3.4e38)
 _POS = np.float32(3.4e38)
-
-# Kernel-only wall time of the calling thread's most recent
-# classify_elements call (dispatch + readback, excluding the host
-# history parse) — bench.py reads this so the hbm_frac roofline
-# fraction divides bytes moved by the DEVICE time, not the whole
-# checker stage. Thread-local: concurrent checkers must not read each
-# other's timing.
-_LAST = threading.local()
-
-
-def last_kernel_seconds() -> float:
-    return getattr(_LAST, "value", 0.0)
-
-
-def modeled_bytes(n_reads: int, n_elements: int) -> int:
-    """Bytes-moved model for one classify_elements dispatch — the
-    denominator side of the membership kernel's ``hbm_frac`` roofline
-    accounting (VERDICT r5 weak #3: the 3.49x ratio carried no evidence
-    of whether it was near the memory-bound ceiling).
-
-    The kernel is elementwise/reduction-only (no matmuls), so its
-    ceiling is HBM bandwidth over the [R, E] matrix passes. Counted per
-    padded cell (Rb x Eb, the shapes actually dispatched):
-
-    * packed H2D transfer (1/8 B) + the bit-unpack write (1 B)
-    * four bool-matrix reads: the masked member uses in m, later,
-      lp, la (4 B)
-    * seen_t f32 write + read for the min-reduce (8 B)
-    * the ``later`` mask write + its three reads (4 B)
-    * lp and la: each a where-select write + max-reduce read (16 B)
-
-    ~33 B/cell total. A LOWER bound — XLA may materialize more
-    intermediates, never fewer passes than the dataflow needs — so the
-    reported fraction is conservative: a fraction near 1 proves
-    memory-bound; a small fraction proves headroom."""
-    Rb, Eb = _bucketed(max(n_reads, 1)), _bucketed(max(n_elements, 1))
-    cells = Rb * Eb
-    per_cell = 0.125 + 1 + 4 + 8 + 4 + 16
-    return int(cells * per_cell)
 
 
 def _build_classify(R: int, E: int):
@@ -157,7 +115,6 @@ def classify_elements(member: np.ndarray, t_read: np.ndarray,
     ev = np.zeros((Eb,), dtype=bool)
     ev[:E] = True
 
-    t0 = time.perf_counter()
     code, stale, latency = fn(jnp.asarray(mem), jnp.asarray(tr),
                               jnp.asarray(rv), jnp.asarray(iv),
                               jnp.asarray(okt), jnp.asarray(hok),
@@ -165,5 +122,4 @@ def classify_elements(member: np.ndarray, t_read: np.ndarray,
     # one batched host transfer (three sequential syncs would pay a
     # device round-trip each)
     code, stale, latency = jax.device_get((code, stale, latency))
-    _LAST.value = time.perf_counter() - t0
     return code[:E], stale[:E], latency[:E]

@@ -19,8 +19,7 @@ the discipline and the evidence:
   in-flight device work, stall time spent at the depth limit, and the
   in-flight high-water — wired into the telemetry registry
   (``dispatch_*`` instruments) and mirrored into the thread-local
-  :func:`last_stats` so
-  bench.py can fold the numbers into its summary line.
+  :func:`last_stats`, which :meth:`DispatchPipeline.stats` reads.
 * A round-trip cost model (:class:`CostModel`) for ``accelerator=auto``
   routing: when the CPU lane can finish a batch before the device's
   round-trip floor, the batch routes to the C++/CPU lane instead of
@@ -45,9 +44,9 @@ from collections import deque
 logger = logging.getLogger("jepsen.pipeline")
 
 # Stats of the calling thread's most recently completed pipeline
-# (results() updates it): bench.py reads this after a timed stage the
-# way elle's bench reads its phase dict. Thread-local — concurrent
-# checkers under bounded_pmap must not clobber each other's stats.
+# (results() updates it); DispatchPipeline.stats() reads it.
+# Thread-local — concurrent checkers under bounded_pmap must not
+# clobber each other's stats.
 _LAST_STATS = threading.local()
 
 

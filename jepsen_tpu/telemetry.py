@@ -1,15 +1,14 @@
 """Unified runtime telemetry: a Prometheus-style metrics registry.
 
 The span log in :mod:`jepsen_tpu.tracing` answers "what happened, when";
-this module answers "how much / how fast / how hot", on every run — not
-just when bench.py happens to execute. It is the missing half of the
-observability pair Jepsen's own suites ship (dgraph's trace.clj spans go
-to Jaeger; its serving stack scrapes Prometheus): a thread-safe registry
-of Counters, Gauges, and log-bucketed Histograms with labels, a
-``timer()`` context manager, timestamped events (nemesis fault windows),
-and exporters for the Prometheus text exposition format
-(``metrics.prom``) plus a JSONL snapshot (``metrics.json``) written into
-the test's store directory.
+this module answers "how much / how fast / how hot", on every run. It
+is the missing half of the observability pair Jepsen's own suites ship
+(dgraph's trace.clj spans go to Jaeger; its serving stack scrapes
+Prometheus): a thread-safe registry of Counters, Gauges, and
+log-bucketed Histograms with labels, a ``timer()`` context manager,
+timestamped events (nemesis fault windows), and exporters for the
+Prometheus text exposition format (``metrics.prom``) plus a JSONL
+snapshot (``metrics.json``) written into the test's store directory.
 
 Zero-cost disabled mode: the module-level default registry is
 :data:`NULL`, whose instrument constructors hand back one shared no-op
@@ -19,9 +18,8 @@ every method on the null instruments is a constant no-op. ``core.run``
 installs a live :class:`Registry` for the duration of a run (unless the
 test map sets ``metrics: False``) and restores the previous one after.
 
-Device helpers (``device_memory_stats``, ``matrix_modeled_flops``) give
-the checker and bench.py one shared vocabulary for memory high-water
-and modeled matrix FLOPs.
+The device helper ``device_memory_stats`` gives the checker its
+memory high-water gauge.
 """
 from __future__ import annotations
 
@@ -666,74 +664,6 @@ def device_memory_peak_bytes() -> int | None:
         if key in stats:
             return int(stats[key])
     return None
-
-
-def matrix_modeled_flops(n_returns: int, n_slots: int,
-                         num_states: int) -> float:
-    """Modeled f32 FLOPs issued by the transfer-matrix kernel for
-    ``n_returns`` returns: each composes one [MV, MV] operator via
-    ~(ceil(log2 S) + 2) dense matmuls (bench.py's roofline accounting;
-    a LOWER bound — the elementwise L build is excluded)."""
-    MV = (1 << n_slots) * num_states
-    n_sq = 0
-    while (1 << n_sq) < n_slots:
-        n_sq += 1
-    return n_returns * (n_sq + 2) * 2.0 * MV ** 3
-
-
-def matrix_phase_model(n_returns: int, n_slots: int, num_states: int,
-                       n_chunks: int = 1, n_keys: int = 1) -> dict:
-    """Modeled FLOP shares of one transfer-matrix dispatch, by phase —
-    the analytic companion to the measured host/device split
-    (ops.jitlin.last_phase_seconds). Three on-device phases:
-
-    * ``matmul`` — the closure squarings + kill-apply + compose per
-      return: (ceil(log2 S) + 2) dense [MV, MV] products.
-    * ``lbuild`` — the elementwise L assembly (each of the MV^2 cells
-      sums S gated products).
-    * ``combine`` — the per-key chunk-product chain: C-1 products per
-      key plus the tot0 compose, amortized over the whole dispatch.
-
-    The shares say where a restructure could possibly pay: when
-    ``lbuild_frac`` + ``combine_frac`` is already small, the residual
-    gap to peak is NOT in those phases — it is fixed per-dispatch
-    overhead (host prep + round trip), which the measured phase split
-    attributes directly."""
-    MV = (1 << n_slots) * num_states
-    # the matmul term IS bench.py's roofline numerator, so the
-    # attribution can never diverge from the fraction it explains
-    matmul = matrix_modeled_flops(n_returns, n_slots, num_states)
-    lbuild = n_returns * 2.0 * n_slots * MV * MV
-    combine = n_keys * n_chunks * 2.0 * MV ** 3
-    total = matmul + lbuild + combine
-    return {
-        "modeled_matmul_frac": round(matmul / total, 4),
-        "modeled_lbuild_frac": round(lbuild / total, 6),
-        "modeled_combine_frac": round(combine / total, 6),
-    }
-
-
-def combine_modeled_hbm_bytes(n_keys: int, n_chunks: int, mv: int,
-                              fused: bool, itemsize: int = 2) -> int:
-    """Modeled HBM traffic of the chunk-product combine stage, per
-    dispatch (bf16 matrices: itemsize 2). The tree combine's
-    ceil(log2 C) levels each read two [MV, MV] products and write one
-    per pair; the fused streaming combine (pallas_matrix._build_combine)
-    reads each chunk product exactly once, reads tot0, and writes only
-    the total — the ratio of the two is the ``combine_fused_reduction``
-    bench.py reports, and ``combine_hbm_frac`` divides the active
-    model's bytes by wall time and measured HBM bandwidth."""
-    cell = mv * mv * itemsize
-    if fused:
-        return n_keys * (n_chunks + 2) * cell
-    total = 0
-    c = n_chunks
-    while c > 1:
-        pairs = c // 2
-        total += pairs * 3 * cell       # read 2, write 1 per pair
-        c = pairs + (c % 2)
-    total += 3 * cell                   # the tot0 compose
-    return n_keys * total
 
 
 @contextmanager

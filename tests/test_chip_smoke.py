@@ -69,6 +69,18 @@ def test_host_bound_phases_hold_to_their_oracles(phase):
     assert line["cold_s"] >= 0 and line["warm_s"] >= 0
 
 
+def test_elle_history_crossed_pairs_are_the_only_anomaly():
+    from jepsen_tpu.elle import list_append
+
+    good = list_append.check(chip_smoke._elle_history(2_000),
+                             accelerator="cpu")
+    assert good["valid?"] is True
+    bad = list_append.check(
+        chip_smoke._elle_history(2_000, crossed_pairs=10), accelerator="cpu")
+    assert bad["valid?"] is False
+    assert "G1c" in bad["anomaly-types"]
+
+
 def test_compile_cache_placement(monkeypatch):
     """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache is
     the fixed <checkout>/.jax_cache."""

@@ -283,6 +283,60 @@ def test_daemon_releases_lease_and_fires_on_final(tmp_path):
     assert ls.held == {}
 
 
+def test_standby_settles_adopted_runs_and_skips_settled_ones(tmp_path):
+    """Failover: daemon A settles one run and leases a live one, then
+    stalls past its TTL. Standby B adopts the live run and settles it,
+    and never finalizes the run A had already settled."""
+    from jepsen_tpu import telemetry
+    from jepsen_tpu.live.daemon import LiveDaemon
+    clock = [1000.0]
+    ls_a = _lease_store(tmp_path, "a", clock, ttl=30.0)
+    ls_b = _lease_store(tmp_path, "b", clock, ttl=30.0)
+    settled, live = tmp_path / "demo" / "p0", tmp_path / "demo" / "l0"
+    ops_settled, ops_live = _register_history(24), _register_history(24, 8)
+    _write_wal(settled, ops_settled)
+    _write_wal(live, ops_live)
+    finals = []
+
+    def record(tr, res):
+        finals.append((tr.label, dict(tr.lease), res.get("valid?")))
+
+    da = LiveDaemon(store_root=tmp_path, accelerator="cpu",
+                    registry=telemetry.Registry(), lease_store=ls_a,
+                    on_final=record)
+    db = LiveDaemon(store_root=tmp_path, accelerator="cpu",
+                    registry=telemetry.Registry(), lease_store=ls_b,
+                    on_final=record)
+
+    def settle(daemon, run_dir, ops):
+        with open(run_dir / "history.jsonl", "w") as f:
+            for op in ops:
+                f.write(json.dumps(op) + "\n")
+        deadline = time.monotonic() + 30
+        n = len(finals)
+        while time.monotonic() < deadline and len(finals) == n:
+            daemon.poll_once()
+
+    try:
+        da.poll_once()  # a leases both runs while they are live
+        assert ls_a.read(settled)["host"] == ls_a.read(live)["host"] == "a"
+        settle(da, settled, ops_settled)
+        assert finals == [("demo/p0", {"host": "a", "epoch": 1}, True)]
+
+        clock[0] += 31.0  # a stalls past its TTL
+        db.poll_once()
+        assert ls_b.read(live)["host"] == "b"
+        assert [tr.label for tr in db.trackers.values()] == ["demo/l0"]
+        settle(db, live, ops_live)
+        for _ in range(3):
+            db.poll_once()
+    finally:
+        da.stop()
+        db.stop()
+    assert finals == [("demo/p0", {"host": "a", "epoch": 1}, True),
+                      ("demo/l0", {"host": "b", "epoch": 2}, True)]
+
+
 # ---------------------------------------------------------------------------
 # ENOSPC: Journal park/drain + truncate rollback, FaultRegistry park
 # ---------------------------------------------------------------------------

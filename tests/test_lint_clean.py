@@ -6,9 +6,9 @@ durability-protocol, telemetry-name).
 This is the machine that turns a future regression of any encoded
 invariant class — a lock taken in the wrong order, a durable artifact
 overwritten in place, a naked ``wait()``, a silent metric rename — into
-a red build instead of a review catch. The wall-clock assertions mirror
-the ``lint_wall_s`` bench bars (< 60 s cold, < 30 s warm) so analysis
-cost regressions fail here before they silently eat the tier-1 budget.
+a red build instead of a review catch. The wall-clock assertions
+(< 60 s cold, < 30 s warm) make analysis cost regressions fail here
+before they silently eat the tier-1 budget.
 """
 from __future__ import annotations
 

@@ -246,3 +246,14 @@ class TestFuzzDeterminism:
             ops, consumed, torn, truncated = parse_wal_chunk_py(
                 data, final=True)
             assert consumed == len(data)
+
+    def test_plain_build_run_agrees_and_covers_the_corpus(self, tmp_path):
+        # a bounded run against the plain -O3 build: zero C-vs-Python
+        # divergences, and every corpus seed and mutation operator fired
+        from jepsen_tpu.fuzz import native as fn
+        res = fn.run_fuzz(4000, seed=1, san=False, store_dir=str(tmp_path))
+        if res["status"] == "no-native":
+            pytest.skip("native ingest extension unavailable")
+        assert res["divergences"] == 0, res["artifacts"]
+        assert set(res["seed_coverage"]) == {n for n, _ in fn.SEEDS}
+        assert set(res["operator_coverage"]) == {n for n, _ in fn.OPERATORS}

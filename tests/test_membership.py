@@ -132,6 +132,22 @@ def test_invoke_records_pre_op_set_and_heals_on_resolve(tmp_path,
                        labels=("f",)).value(f="shrink") == 1
 
 
+def test_reconfig_cycles_leave_nothing_pending_or_unhealed(tmp_path):
+    """Alternating shrink/grow through record, invoke and resolve: once
+    settled, no op is pending and every durable record is healed."""
+    st = FakeClusterState(tmp_path / "m.json", nodes=NODES, settle_s=0.0)
+    n = membership.MembershipNemesis(st, poll_interval=3600)
+    registry = FaultRegistry(tmp_path / "faults.jsonl")
+    test = {"nodes": NODES, "_faults": registry}
+    for i in range(20):
+        f = "shrink" if i % 2 == 0 else "grow"
+        n.invoke(test, {"type": "info", "f": f, "value": "n5"})
+    assert n.pending_count() == 0
+    assert registry.unhealed() == []
+    registry.close()
+    assert json.loads((tmp_path / "m.json").read_text()) == sorted(NODES)
+
+
 def test_unresolved_op_stays_unhealed_and_replays(tmp_path):
     """A reconfig that never resolves (settle window) leaves its entry
     on the books; replay_unhealed restores the recorded pre-op set

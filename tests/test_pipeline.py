@@ -209,11 +209,54 @@ def test_batch_check_auto_keeps_big_on_device(monkeypatch):
     assert par.last_route() == "device"
 
 
+N_PROCS = 5
+
+
+def _block_stream(n_blocks: int, n_procs: int = N_PROCS, n_values: int = 100,
+                  start_block: int = 0):
+    """Vectorized valid single-register event stream: block t = P invokes
+    (proc 0 writes w_t = t mod V; procs 1..P-1 read w_{t-1}) then P
+    returns. Reads linearize before the concurrent write, so the history
+    is linearizable by construction. O(E) numpy, no Python per-op loop —
+    this is what makes multi-million-event scaling runs generatable.
+
+    ``start_block`` continues a longer logical history: block numbering
+    (and so the read/write value sequence) picks up at that offset, so
+    consecutive segments chain correctly through the carried frontier."""
+    from jepsen_tpu.checker.linear_encode import EventStream
+    from jepsen_tpu.history import Intern
+    from jepsen_tpu.models import CAS_F_READ, CAS_F_WRITE
+
+    P, V = n_procs, n_values
+    intern = Intern()
+    for v in range(V):
+        intern.id(v)  # ids 1..V
+
+    t = np.arange(start_block, start_block + n_blocks, dtype=np.int64)
+    w_id = (t % V).astype(np.int32) + 1              # this block's write
+    r_id = np.where(t > 0, ((t - 1) % V).astype(np.int32) + 1, 0)  # read
+
+    kind = np.tile(np.concatenate([np.zeros(P, np.int8), np.ones(P, np.int8)]),
+                   n_blocks)
+    slot = np.tile(np.concatenate([np.arange(P), np.arange(P)]).astype(np.int32),
+                   n_blocks)
+    f = np.zeros((n_blocks, 2 * P), np.int32)
+    f[:, 0] = CAS_F_WRITE
+    f[:, 1:P] = CAS_F_READ
+    a = np.zeros((n_blocks, 2 * P), np.int32)
+    a[:, 0] = w_id
+    a[:, 1:P] = r_id[:, None]
+    E = n_blocks * 2 * P
+    return EventStream(
+        kind=kind, slot=slot, f=f.reshape(-1), a=a.reshape(-1),
+        b=np.zeros(E, np.int32), op_index=np.arange(E, dtype=np.int32),
+        n_slots=P, n_ops=n_blocks * P, intern=intern)
+
+
 def test_resume_chain_after_donation_gate():
     """Segmented resume chaining stays correct under the donation
     machinery (on CPU the gate collapses both wrappers; the chain's
     verdicts must hold either way)."""
-    from bench import _block_stream
     from jepsen_tpu.ops.jitlin import matrix_check_resume
 
     s0 = _block_stream(300, n_procs=3, n_values=4)
@@ -250,12 +293,3 @@ def test_checker_exports_phase_gauges(monkeypatch):
     phases = {r["labels"]["phase"] for r in reg.snapshot()
               if r["name"] == "checker_matrix_phase_seconds"}
     assert {"prepass", "grids", "dispatch", "fetch"} <= phases
-
-
-def test_matrix_phase_model_shares():
-    m = telemetry.matrix_phase_model(64_000, 5, 8, 256, 1)
-    assert m["modeled_matmul_frac"] > 0.99
-    assert m["modeled_lbuild_frac"] < 0.01
-    total = (m["modeled_matmul_frac"] + m["modeled_lbuild_frac"]
-             + m["modeled_combine_frac"])
-    assert total == pytest.approx(1.0, abs=0.01)

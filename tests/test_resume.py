@@ -149,10 +149,11 @@ def test_matrix_segmented_ckpt_resume_bit_identical(tmp_path, monkeypatch,
     s = _stream(600, plant_anomaly_at=plant)
     n_cuts = len(quiescent_cuts(np.asarray(s.kind), 512))
     path = tmp_path / "check.ckpt"
-    full = matrix_check_segmented(
-        s, max_segment=512,
-        ckpt=CheckpointStore(path, interval_s=0.0, resume=False))
+    store = CheckpointStore(path, interval_s=0.0, resume=False)
+    full = matrix_check_segmented(s, max_segment=512, ckpt=store)
     assert path.exists()
+    # interval 0: a persist after every segment but the last
+    assert store.writes >= n_cuts - 1
 
     calls = _count_segments(monkeypatch)
     resumed = matrix_check_segmented(

@@ -173,10 +173,7 @@ def test_scc_screen_parity(tpu_device):
 def test_elle_device_parity(tpu_device):
     """The list-append check's device screen agrees with the CPU path on
     a valid and an anomalous history."""
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from bench import _elle_history
+    from chip_smoke import _elle_history
     from jepsen_tpu.elle import list_append
 
     good = _elle_history(2_000)

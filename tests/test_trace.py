@@ -286,6 +286,8 @@ class TestTracedRun:
     def test_clean_run_trace_and_no_flight_dump(self, tmp_path):
         result = core.run(_register_test(tmp_path))
         assert result["results"]["valid?"] is True
+        # every tracer on: still one invoke per generated op
+        assert sum(op["type"] == "invoke" for op in result["history"]) == 60
         d = store.test_dir(result)
         evs = _strict_load(d / "trace.json")
         tracks = _tracks_used(evs)
