@@ -22,7 +22,7 @@ from jepsen_tpu.models import CAS_F_CAS, CAS_F_READ, CAS_F_WRITE, Model, is_inco
 
 
 def cas_register_step_py(state: int, f: int, a: int, b: int) -> tuple[int, bool]:
-    """Pure-python twin of models.cas_register_spec().step_ids."""
+    """Pure-python twin of models.cas_register_step_ids."""
     if f == CAS_F_READ:
         return state, (a == 0 or a == state)
     if f == CAS_F_WRITE:

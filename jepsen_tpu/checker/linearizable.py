@@ -85,7 +85,6 @@ class LinearizableChecker(Checker):
         # ladder module's env-tunable defaults
         self.watchdog_s = watchdog_s
         self.breaker_threshold = breaker_threshold
-        self._kernel = None
         self._ladder = None
 
     def _encoding(self, history, ir=None):
@@ -134,11 +133,9 @@ class LinearizableChecker(Checker):
         return None
 
     def _tpu_kernel(self, spec):
-        if self._kernel is None:
-            from jepsen_tpu.ops.jitlin import JitLinKernel
-            self._kernel = JitLinKernel(step_ids=spec.step_ids,
-                                        init_state=spec.init_state)
-        return self._kernel
+        from jepsen_tpu.ops.jitlin import JitLinKernel
+        return JitLinKernel(step_ids=spec.step_ids,
+                            init_state=spec.init_state)
 
     def check(self, test, history, opts):
         with trace.phase(trace.CHECK_SPAN, ops=len(history), keys=1):

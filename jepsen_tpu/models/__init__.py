@@ -15,6 +15,7 @@ Two forms exist side by side:
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable
 
@@ -390,32 +391,34 @@ class IntSpec:
 CAS_F_READ, CAS_F_WRITE, CAS_F_CAS = 0, 1, 2
 
 
+def cas_register_step_ids(state, f, a, b):
+    """The CAS register's transition over interned ids. One function for
+    every spec, so the device kernel caches, which key on the step
+    function, hit across checkers."""
+    import jax.numpy as jnp
+    is_read = f == CAS_F_READ
+    is_write = f == CAS_F_WRITE
+    is_cas = f == CAS_F_CAS
+    ok = (
+        (is_read & ((a == 0) | (a == state)))
+        | is_write
+        | (is_cas & (state == a))
+    )
+    new_state = jnp.where(is_write, a, jnp.where(is_cas & ok, b, state))
+    return new_state, ok
+
+
 def cas_register_spec(init_state: int = 0) -> IntSpec:
     """Device-encodable CAS register. Ops encode as (f, a, b):
     read v -> (0, v_id, 0); write v -> (1, v_id, 0); cas [u,v] -> (2, u_id, v_id).
     A read of value-id 0 (None) matches any state — used for indeterminate
     reads."""
-
-    def step_ids(state, f, a, b):
-        import jax.numpy as jnp
-        is_read = f == CAS_F_READ
-        is_write = f == CAS_F_WRITE
-        is_cas = f == CAS_F_CAS
-        ok = (
-            (is_read & ((a == 0) | (a == state)))
-            | is_write
-            | (is_cas & (state == a))
-        )
-        new_state = jnp.where(is_write, a, jnp.where(is_cas & ok, b, state))
-        return new_state, ok
-
-    return IntSpec("cas-register", init_state, 3, step_ids)
+    return IntSpec("cas-register", init_state, 3, cas_register_step_ids)
 
 
 def register_spec(init_state: int = 0) -> IntSpec:
     """Read/write register (no cas) — same encoding minus cas."""
-    spec = cas_register_spec(init_state)
-    return IntSpec("register", init_state, 2, spec.step_ids)
+    return IntSpec("register", init_state, 2, cas_register_step_ids)
 
 
 @dataclass(frozen=True)
@@ -458,7 +461,14 @@ def multi_register_spec(n_keys: int = 3, n_values: int = 5) -> IntSpec:
     digits of ``a``: 0 none, 1 read-None, 2+v read-v, 2+V+v write-v.
     ``step_ids`` decodes with a static loop over keys (shape-polymorphic
     jnp arithmetic, no data-dependent control flow)."""
-    V, K = n_values, n_keys
+    return IntSpec(f"multi-register-{n_keys}x{n_values}", 0, 1,
+                   _multi_register_step(n_keys, n_values))
+
+
+@functools.lru_cache(maxsize=64)
+def _multi_register_step(K: int, V: int):
+    """One step function per (K, V), so the device kernel caches, which
+    key on the step function, hit across checkers."""
     SB = V + 1          # state digit base
     AB = 2 * V + 2      # action digit base
     if AB ** K >= (1 << 31):
@@ -480,7 +490,7 @@ def multi_register_spec(n_keys: int = 3, n_values: int = 5) -> IntSpec:
             new_state = new_state + (wdigit - digit) * (SB ** k)
         return new_state, ok
 
-    return IntSpec(f"multi-register-{K}x{V}", 0, 1, step_ids)
+    return step_ids
 
 
 @dataclass(frozen=True)

@@ -35,9 +35,12 @@ import logging
 import os
 import threading
 import time
+from collections import OrderedDict
 from functools import partial
 
 import numpy as np
+
+from jepsen_tpu.models import cas_register_step_ids
 
 logger = logging.getLogger("jepsen.jitlin")
 
@@ -1065,7 +1068,7 @@ def matrix_check(stream, step_ids=None, init_state: int = 0,
     (both probe-gated, demote-not-fail — doc/performance.md "Packed
     boolean kernels")."""
     if step_ids is None:
-        step_ids = _default_step_ids()
+        step_ids = cas_register_step_ids
     num_states = num_states if num_states is not None else len(stream.intern)
     kind, slot = np.asarray(stream.kind), np.asarray(stream.slot)
     # gate BEFORE the O(E) prepass: everything the gate needs is
@@ -1114,7 +1117,7 @@ def matrix_check_resume(stream, tot0=None, step_ids=None,
     and single-device segments (the ladder's sharded→device demotion
     mid-chain is sound)."""
     if step_ids is None:
-        step_ids = _default_step_ids()
+        step_ids = cas_register_step_ids
     if num_states is None:
         num_states = len(stream.intern)
     V = _bucket(num_states, floor=8)
@@ -1152,7 +1155,7 @@ def matrix_segmented_config(S, V, init_state, num_states, max_segment,
     interrupt and resume must discard on the config instead."""
     from jepsen_tpu.checker.checkpoint import step_identity
     if step_ids is None:
-        step_ids = _default_step_ids()
+        step_ids = cas_register_step_ids
     return {"path": "matrix", "S": S, "V": V, "init_state": init_state,
             "num_states": num_states, "max_segment": max_segment,
             "variant": variant, "combine_fused": combine_fused,
@@ -1196,7 +1199,7 @@ def matrix_check_segmented(stream, step_ids=None, init_state: int = 0,
     the carry is the same replicated product either way, so a chain
     may shrink or demote its mesh between segments freely."""
     if step_ids is None:
-        step_ids = _default_step_ids()
+        step_ids = cas_register_step_ids
     if num_states is None:
         num_states = len(stream.intern)
     V = _bucket(num_states, floor=8)
@@ -1312,7 +1315,7 @@ def matrix_check_batch(streams, step_ids=None, init_state: int = 0,
     import jax
 
     if step_ids is None:
-        step_ids = _default_step_ids()
+        step_ids = cas_register_step_ids
     if num_states is None:
         num_states = max(len(s.intern) for s in streams)
     V = _bucket(num_states, floor=8)
@@ -1611,28 +1614,18 @@ def _matrix_dispatch(preps, S, R_max, V, step_ids, init_state, mesh,
 
 
 _MATRIX_CACHE: dict = {}
-_DEFAULT_STEP_IDS = None
-
-
-def _default_step_ids():
-    """One shared default spec — a fresh object per call would defeat
-    the id()-keyed compile cache."""
-    global _DEFAULT_STEP_IDS
-    if _DEFAULT_STEP_IDS is None:
-        from jepsen_tpu.models import cas_register_spec
-        _DEFAULT_STEP_IDS = cas_register_spec().step_ids
-    return _DEFAULT_STEP_IDS
 
 
 def _matrix_cache(S, V, step_ids, init_state, T, C, B=1, mesh=None):
     # the uop-table length is a runtime array shape — jax.jit retraces on
     # it, so it doesn't belong in this key. A mesh keys on its device ids
     # + axis names: parallel.auto_mesh caches one Mesh per device count,
-    # so repeated sharded dispatches hit the same compiled kernel.
+    # so repeated sharded dispatches hit the same compiled kernel. The
+    # step function keys as itself, as in _FRONTIER_CACHE.
     mesh_key = (None if mesh is None else
                 (tuple(int(d.id) for d in mesh.devices.flat),
                  tuple(mesh.axis_names)))
-    key = (S, V, id(step_ids), init_state, T, C, B, mesh_key)
+    key = (S, V, step_ids, init_state, T, C, B, mesh_key)
     fn = _MATRIX_CACHE.get(key)
     if fn is None:
         if mesh is not None:
@@ -1741,7 +1734,7 @@ _FORENSICS_CACHE: dict = {}
 
 
 def _forensics_cache(S, V, step_ids, T, C):
-    key = (S, V, id(step_ids), T, C)
+    key = (S, V, step_ids, T, C)
     fk = _FORENSICS_CACHE.get(key)
     if fk is None:
         fk = _build_forensics_kernel(S, V, step_ids, T, C)
@@ -1800,7 +1793,7 @@ def matrix_localize(stream, tot0=None, step_ids=None, init_state: int = 0,
     import jax.numpy as jnp
 
     if step_ids is None:
-        step_ids = _default_step_ids()
+        step_ids = cas_register_step_ids
     if num_states is None:
         num_states = len(stream.intern)
     V = _bucket(num_states, floor=8)
@@ -2053,15 +2046,61 @@ def _slice_stream(stream, lo: int, hi: int):
     return seg
 
 
+# The jitted frontier-scan kernels of the whole process, shared by every
+# JitLinKernel: a new checker (Jepsen makes one per test run) reuses the
+# scan an earlier one traced and lowered instead of lowering it again.
+# The key holds the step function itself, not its id(), so a freed
+# closure's address can never hand one model another model's kernel.
+# Least recently used first out; builds take the lock, so two threads
+# (Compose's checkers under bounded_pmap) that miss at once share one
+# entry.
+FRONTIER_CACHE_SIZE = 32
+_FRONTIER_CACHE: OrderedDict = OrderedDict()
+_FRONTIER_LOCK = threading.Lock()
+
+
+def _frontier_kernel(kind: str, S: int, width: int, batched: bool,
+                     resume: bool, step_ids, init_state: int):
+    """The cached kernel of ``kind`` ("dense": ``width`` is the bucketed
+    state count; "sparse": the frontier capacity K), built on a miss."""
+    key = (kind, S, width, batched, resume, step_ids, init_state)
+    with _FRONTIER_LOCK:
+        fn = _FRONTIER_CACHE.get(key)
+        if fn is not None:
+            _FRONTIER_CACHE.move_to_end(key)
+            return fn
+        fn = _build_frontier_kernel(*key)
+        _FRONTIER_CACHE[key] = fn
+        if len(_FRONTIER_CACHE) > FRONTIER_CACHE_SIZE:
+            _FRONTIER_CACHE.popitem(last=False)
+        return fn
+
+
+def _build_frontier_kernel(kind, S, width, batched, resume, step_ids,
+                           init_state):
+    import jax
+    if kind == "dense":
+        run = _build_dense_step(S, width, step_ids, init_state)
+
+        def init_carry():
+            return (run.init_table(),)
+    else:
+        run = _build_step(S, width, step_ids, init_state)
+        init_carry = run.init_frontier
+    if resume:
+        return _ResumeKernel(jax.jit(run.resume), init_carry)
+    return jax.jit(jax.vmap(run)) if batched else jax.jit(run)
+
+
 class JitLinKernel:
-    """Compiled-kernel cache keyed by backend + (S, K|V, batched?)."""
+    """One model's view of the process-wide frontier-kernel cache: its
+    step function and initial state, with the shape key picked per
+    call."""
 
     def __init__(self, step_ids=None, init_state: int = 0):
-        # the shared default spec keeps id(step_ids)-keyed compile caches
-        # (matrix kernels) warm across kernel instances
-        self.step_ids = step_ids if step_ids is not None else _default_step_ids()
+        self.step_ids = (step_ids if step_ids is not None
+                         else cas_register_step_ids)
         self.init_state = init_state
-        self._cache: dict = {}
 
     def _get(self, S: int, K: int, batched: bool, num_states: int | None = None,
              resume: bool = False):
@@ -2071,31 +2110,12 @@ class JitLinKernel:
         ``resume`` the returned callable takes and returns the frontier
         carry (dense: +table; sparse: +mask,state) for segmented
         verification; it also exposes ``.init_carry()``."""
-        import jax
         if _dense_ok(S, num_states):
-            vb = _bucket(num_states, floor=16)
-            key = ("dense", S, vb, batched, resume)
-            fn = self._cache.get(key)
-            if fn is None:
-                run = _build_dense_step(S, vb, self.step_ids, self.init_state)
-                if resume:
-                    fn = _ResumeKernel(jax.jit(run.resume),
-                                       lambda: (run.init_table(),))
-                else:
-                    fn = jax.jit(jax.vmap(run)) if batched else jax.jit(run)
-                self._cache[key] = fn
-            return fn
-        key = ("sparse", S, K, batched, resume)
-        fn = self._cache.get(key)
-        if fn is None:
-            run = _build_step(S, K, self.step_ids, self.init_state)
-            if resume:
-                fn = _ResumeKernel(jax.jit(run.resume),
-                                   lambda: run.init_frontier())
-            else:
-                fn = jax.jit(jax.vmap(run)) if batched else jax.jit(run)
-            self._cache[key] = fn
-        return fn
+            kind, width = "dense", _bucket(num_states, floor=16)
+        else:
+            kind, width = "sparse", K
+        return _frontier_kernel(kind, S, width, batched, resume,
+                                self.step_ids, self.init_state)
 
     def check(self, stream, capacity: int = 256):
         """Single history. Returns (alive, died_event, overflow, peak).

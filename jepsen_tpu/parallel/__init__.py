@@ -432,8 +432,6 @@ def pad_to_multiple(batch: dict, multiple: int) -> tuple[dict, int]:
     return out, B
 
 
-_DEFAULT_KERNEL = None
-
 # How the most recent batch_check on THIS thread settled: "device"
 # (single-device matrix/scan kernels), "mesh" (the shard_map multi-device
 # path), or "cpu" (the auto-routed native/Python lane).
@@ -446,17 +444,6 @@ _ROUTE = threading.local()
 def last_route() -> str:
     """The lane the calling thread's most recent batch_check took."""
     return getattr(_ROUTE, "value", "device")
-
-
-def _default_kernel():
-    """One shared default JitLinKernel — its compile cache must survive
-    across batch_check calls (a fresh instance per call would re-jit the
-    vmapped kernel every time)."""
-    global _DEFAULT_KERNEL
-    if _DEFAULT_KERNEL is None:
-        from jepsen_tpu.ops.jitlin import JitLinKernel
-        _DEFAULT_KERNEL = JitLinKernel()
-    return _DEFAULT_KERNEL
 
 
 def batch_check(streams: Sequence, capacity: int = 256, mesh=None,
@@ -494,11 +481,8 @@ def batch_check(streams: Sequence, capacity: int = 256, mesh=None,
         MATRIX_MIN_RETURNS, MATRIX_SUB_KEYS, _bucket, matrix_check_batch)
 
     if kernel is None:
-        if step_ids is None and init_state == 0:
-            kernel = _default_kernel()
-        else:
-            from jepsen_tpu.ops.jitlin import JitLinKernel
-            kernel = JitLinKernel(step_ids=step_ids, init_state=init_state)
+        from jepsen_tpu.ops.jitlin import JitLinKernel
+        kernel = JitLinKernel(step_ids=step_ids, init_state=init_state)
     streams = list(streams)
     _ROUTE.value = "device"
     # an explicit mesh is an operator force (checker_sharded: True) —
@@ -586,17 +570,16 @@ def _cpu_batch_maybe(streams, kernel, force: bool = False):
     # the host lane runs the CAS-register search (the Python twin
     # honors any init_state; the native C++ lane hardcodes init id 0) —
     # other specs keep the device lane, whose kernels are spec-generic.
-    # The spec is recognized by its closure origin: cas_register_spec
-    # builds a fresh step_ids per call, so identity against the shared
-    # default is not enough (the checker builds its own spec instance).
-    qn = getattr(kernel.step_ids, "__qualname__", "")
-    if not qn.startswith("cas_register_spec."):
+    # Every CAS-register spec shares one step function.
+    from jepsen_tpu.models import cas_register_step_ids
+    if kernel.step_ids is not cas_register_step_ids:
         if force:
             # an EXPLICIT cpu request that can't be honored must not
             # silently become a device dispatch
             logger.warning(
                 "accelerator=cpu requested but kernel spec %r has no "
-                "host twin in batch_check; using the device lane", qn)
+                "host twin in batch_check; using the device lane",
+                getattr(kernel.step_ids, "__qualname__", ""))
         return None
     init_state = kernel.init_state
     total_events = sum(len(s.kind) for s in streams)
@@ -652,8 +635,8 @@ def _scan_batch(streams, capacity, mesh, kernel, n_states):
         fn = kernel._get(S, capacity, batched=True, num_states=n_states)
         compiled = fn._cache_size()
         out = fn(*arrays)
-        # a new jitted function (a new kernel instance) or a new shape
-        # traces and lowers the scan again
+        # a shape key new to the process-wide kernel cache, or a new
+        # array shape, traces and lowers the scan again
         span.set(lowered=int(fn._cache_size() > compiled))
     with trace.phase("dispatch.readback"):
         # ONE batched host transfer: each np.asarray is a full device

@@ -155,7 +155,7 @@ def batch_64x1k():
              for s in streams[:sub]]
     R_max = max(p[0].shape[0] for p in preps)
     C, Tk = jitlin._matrix_plan(sub, S, R_max, V, None)
-    run = jitlin._matrix_cache(S, V, jitlin._default_step_ids(), 0, Tk, C,
+    run = jitlin._matrix_cache(S, V, jitlin.cas_register_step_ids, 0, Tk, C,
                                sub)
     grids, uops = jitlin._matrix_grids(preps, S, V, sub, C, Tk, None)
     pend, ids, slots, valid = (np.asarray(g) for g in grids)

@@ -8,7 +8,7 @@ import json
 import subprocess
 import sys
 import threading
-from collections import defaultdict
+from collections import OrderedDict, defaultdict
 from pathlib import Path
 
 import pytest
@@ -17,6 +17,7 @@ from jepsen_tpu import independent
 from jepsen_tpu import trace as trace_mod
 from jepsen_tpu.checker.ladder import Backend, BackendLadder, Unavailable
 from jepsen_tpu.checker.linearizable import LinearizableChecker
+from jepsen_tpu.ops import jitlin
 from jepsen_tpu.trace.flight import FlightRecorder
 from jepsen_tpu.trace.perfetto import PerfettoSink
 
@@ -27,16 +28,23 @@ SPANS = {"check", "encode.ir", "encode.split", "encode.stream",
          "dispatch.readback", "settle.report", "settle.explain"}
 
 
-def register_history(rounds: int, key=None, bad: bool = False) -> list:
+def register_history(rounds: int, key=None, bad: bool = False,
+                     read_first=None) -> list:
     """10 processes writing at once, ``rounds`` times, then reading the
     last value; ten ops in flight keep the check on the frontier scan.
-    ``bad`` makes the first read return a value never written."""
+    ``bad`` makes the first read return a value never written;
+    ``read_first`` opens with ten reads of that value, before any
+    write."""
     h: list = []
 
     def op(t, p, f, v):
         h.append({"type": t, "process": p, "f": f, "time": len(h),
                   "value": v if key is None else [key, v]})
 
+    if read_first is not None:
+        for t in ("invoke", "ok"):
+            for p in range(10):
+                op(t, p, "read", None if t == "invoke" else read_first)
     for r in range(rounds):
         for t in ("invoke", "ok"):
             for p in range(10):
@@ -84,30 +92,39 @@ def by_check(spans) -> dict:
     return out
 
 
-def test_check_phases_reach_the_profiler(tmp_path):
+def test_check_phases_reach_the_profiler(tmp_path, monkeypatch):
+    # a clean process-wide kernel cache, whatever ran before in the process
+    monkeypatch.setattr(jitlin, "_FRONTIER_CACHE", OrderedDict())
     chk = LinearizableChecker(accelerator="tpu")
     bad = register_history(3, bad=True)
     results = []
 
+    def keyed_check():
+        return independent.checker(
+            LinearizableChecker(accelerator="tpu")).check(
+                {}, keyed_history(), {})
+
     def run():
         results.append(chk.check({}, bad, {}))
         results.append(chk.check({}, bad, {}))
-        results.append(independent.checker(
-            LinearizableChecker(accelerator="tpu")).check(
-                {}, keyed_history(), {}))
+        results.append(keyed_check())
+        results.append(LinearizableChecker(accelerator="tpu").check(
+            {}, bad, {}))
+        results.append(keyed_check())
 
     spans = profiled_spans(tmp_path, run)
-    assert [r["valid?"] for r in results] == [False, False, False]
+    assert [r["valid?"] for r in results] == [False] * 5
     assert "-frontier" in results[0]["algorithm"]
     assert {name for _, name, _ in spans} == SPANS
     checks = by_check(spans)
-    assert len(checks) == 3
-    first, second, keyed = (checks[k] for k in sorted(checks))
-    for one in (first, second, keyed):
+    assert len(checks) == 5
+    first, second, keyed, fresh, fresh_keyed = (checks[k]
+                                                for k in sorted(checks))
+    for one in (first, second, keyed, fresh, fresh_keyed):
         assert [n for _, n, _ in one].count("check") == 1
     # the rung and its dispatch run on the ladder's watchdog thread,
     # under the id of the check that dispatched them
-    for one in (first, second):
+    for one in (first, second, fresh):
         [check_line] = [ln for ln, n, _ in one if n == "check"]
         off_thread = {n for ln, n, _ in one if ln != check_line}
         assert off_thread == {"ladder.rung", "dispatch.pad",
@@ -121,10 +138,13 @@ def test_check_phases_reach_the_profiler(tmp_path):
     def lowered(one):
         return [s["lowered"] for _, n, s in one if n == "dispatch.call"]
 
-    # a fresh checker lowers its scan; a second check through it does not
+    # the first check of a shape lowers its scan; a second check of it
+    # does not, through the same checker or a fresh one
     assert lowered(first) == [1]
     assert lowered(second) == [0]
     assert lowered(keyed) == [1]
+    assert lowered(fresh) == [0]
+    assert lowered(fresh_keyed) == [0]
     stats = {n: s for _, n, s in keyed}
     assert stats["check"]["keys"] == 4
     assert stats["check"]["ops"] == len(keyed_history())
@@ -139,7 +159,8 @@ def test_check_phases_reach_the_profiler(tmp_path):
     assert stats["settle.explain"]["keys"] == 1
 
 
-def test_check_phases_on_the_checker_track(tmp_path):
+def test_check_phases_on_the_checker_track(tmp_path, monkeypatch):
+    monkeypatch.setattr(jitlin, "_FRONTIER_CACHE", OrderedDict())
     p = tmp_path / "trace.json"
     tracer = trace_mod.RunTracer(perfetto=PerfettoSink(p))
     with trace_mod.use(tracer):
